@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import Platform, Processor, Workflow, schedule
+from repro.launch.mesh import make_mesh
 from repro.runtime.pipeline import pipeline_apply, stack_stage_params
 
 
@@ -64,7 +65,7 @@ def main():
         y, _ = jax.lax.scan(layer, x, p["w"])
         return y
 
-    mesh = jax.make_mesh((n_stages,), ("stage",))
+    mesh = make_mesh((n_stages,), ("stage",))
     x = jnp.asarray(rng.normal(size=(batch, d)), jnp.float32)
     y_target = jnp.asarray(rng.normal(size=(batch, d)), jnp.float32)
 

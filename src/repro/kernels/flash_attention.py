@@ -22,11 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships the TPU compiler params as TPUCompilerParams;
-# newer releases renamed it to CompilerParams.  Support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 __all__ = ["flash_attention_bhsd"]
 
 _NEG_INF = -1e30
@@ -123,7 +118,7 @@ def flash_attention_bhsd(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,11 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (jax locks the
-# device count on first init); everything else follows.
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-For each cell this proves the distribution config is coherent:
+A compile-only rehearsal on 512 placeholder CPU host devices: nothing
+runs, and no number it records was measured on a chip.  For each cell
+this proves the distribution config is coherent:
 
 * ``jax.jit(step).lower(**input_specs).compile()`` succeeds on the
   single-pod (16, 16) mesh and the 2-pod (2, 16, 16) mesh,
@@ -13,8 +10,8 @@ For each cell this proves the distribution config is coherent:
 * ``compiled.cost_analysis()`` + post-SPMD collective parsing produce
   the roofline terms (compute / memory / collective).
 
-Results are cached as JSON under ``experiments/dryrun/`` — benchmarks
-and EXPERIMENTS.md §Dry-run/§Roofline read from there.
+Results are cached as JSON under ``experiments/dryrun/``; the
+benchmarks' roofline tables read from there.
 
 Usage::
 
@@ -24,6 +21,7 @@ Usage::
 import argparse
 import json
 import logging
+import os
 import sys
 import time
 import traceback
@@ -44,7 +42,22 @@ from repro.launch.steps import (
 # a __main__ logger would sit outside the "repro" handler subtree
 _log = logging.getLogger("repro.launch.dryrun")
 
-# --- hardware model (TPU v5e target) ---------------------------------- #
+N_HOST_DEVICES = 512   # 2 pods x 16 x 16
+
+
+def use_host_devices(n: int = N_HOST_DEVICES) -> None:
+    """Run JAX on ``n`` placeholder CPU host devices.
+
+    Must be called before JAX initialises a backend (the device count
+    is fixed then); importing this module sets nothing.
+    """
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={n}").strip()
+    jax.config.update("jax_platforms", "cpu")
+
+
+# --- hardware model of the dry run (TPU v5e target) ------------------- #
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
 HBM_BW = 819e9               # bytes/s per chip
 LINK_BW = 50e9               # bytes/s per ICI link
@@ -139,8 +152,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.5: one dict per program
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     if save_hlo is not None:
         _write_hlo(save_hlo, hlo_text)
@@ -258,6 +269,7 @@ def cell_path(arch: str, shape: str, multi_pod: bool, tag: str = "") -> Path:
 def main(argv=None) -> int:
     from repro.obs import setup_logging
     setup_logging()  # CLI entry point: bare messages on stdout
+    use_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

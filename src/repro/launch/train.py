@@ -2,11 +2,12 @@
 
 Two modes:
 
-* ``--smoke`` (default on CPU): reduced config of the selected arch,
-  runs real steps through the fault-tolerant Trainer.
-* ``--production``: builds the full-size bundle against the production
-  mesh and lowers it (the execution path used on real TPU slices; on
-  this host it verifies the program end-to-end up to compilation).
+* default: reduced config of the selected arch, runs real steps
+  through the fault-tolerant Trainer.
+* ``--production``: a compile-only rehearsal — builds the full-size
+  bundle against the 16×16 production mesh on 512 placeholder CPU host
+  devices and lowers + compiles it (the dry run); nothing executes on
+  a chip.
 
 Examples::
 
@@ -19,9 +20,10 @@ from __future__ import annotations
 import argparse
 import sys
 
-from repro.configs import get_config, get_smoke_config, shape_by_name
-from repro.configs.base import ShapeConfig
+from repro.configs.base import ShapeConfig, get_smoke_config
 from repro.runtime import FailureInjector, Trainer, TrainerConfig
+
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -35,17 +37,19 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
     ap.add_argument("--ckpt-every", type=int, default=10)
-    ap.add_argument("--production", action="store_true")
+    ap.add_argument("--production", action="store_true",
+                    help="compile-only rehearsal of the full-size train "
+                         "step on CPU host devices (the dry run)")
     ap.add_argument("--inject-fault-at", type=int, default=None)
     args = ap.parse_args(argv)
 
     if args.production:
-        # full config, production mesh, lower + compile (no execution
-        # on this CPU host; on TPU this object is what runs)
-        from repro.launch.dryrun import run_cell
+        from repro.launch.dryrun import run_cell, use_host_devices
+        use_host_devices()
         result = run_cell(args.arch, args.shape, multi_pod=False)
         return 0 if result["status"] == "ok" else 1
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     shape = ShapeConfig("smoke_train", args.seq_len, args.batch, "train")
     injector = None

@@ -208,11 +208,14 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
     if sliding_window > 0:
         mask = mask & (k_pos[None, :] > clen[:, None] - 1 - sliding_window)
     s = jnp.where(mask[:, None, None, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    # normalise after the PV product, in f32, as the prefill path does
+    # (``_chunked_gqa``), so decode and prefill round alike
+    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
     out = jax.lax.dot_general(
         p.astype(v_cache.dtype), v_cache,
         (((4,), (1,)), ((0, 1), (0, 2))),
         preferred_element_type=jnp.float32)      # [B, Hkv, 1, G, hd]
+    out = out / p.sum(axis=-1)[..., None]
     return out.transpose(0, 2, 1, 3, 4).reshape(b, one, hq, hd).astype(
         q.dtype)
 

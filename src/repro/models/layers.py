@@ -6,6 +6,8 @@ Python-level data-dependent control flow.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,11 +24,27 @@ __all__ = [
 
 
 class Initializer:
-    """Deterministic param initializer with a fan-in scaled normal."""
+    """Deterministic param initializer with a fan-in scaled normal.
+
+    Inside ``with init.stacked(n):`` every array gets a leading ``[n]``
+    dimension, so the ``n`` copies of a scanned layer are drawn in one
+    call and never built by stacking per-layer arrays (which would hold
+    both at once).
+    """
 
     def __init__(self, seed: int, param_dtype=jnp.bfloat16):
         self.key = jax.random.PRNGKey(seed)
         self.param_dtype = param_dtype
+        self.lead: tuple[int, ...] = ()
+
+    @contextlib.contextmanager
+    def stacked(self, n: int):
+        prev = self.lead
+        self.lead = prev + (n,)
+        try:
+            yield self
+        finally:
+            self.lead = prev
 
     def next_key(self):
         self.key, sub = jax.random.split(self.key)
@@ -35,14 +53,20 @@ class Initializer:
     def normal(self, shape, fan_in: int | None = None, scale: float = 1.0):
         fan = fan_in if fan_in is not None else shape[0]
         std = scale / np.sqrt(max(fan, 1))
-        x = jax.random.normal(self.next_key(), shape, dtype=jnp.float32) * std
+        x = jax.random.normal(self.next_key(), self.lead + tuple(shape),
+                              dtype=jnp.float32) * std
         return x.astype(self.param_dtype)
 
     def zeros(self, shape):
-        return jnp.zeros(shape, dtype=self.param_dtype)
+        return jnp.zeros(self.lead + tuple(shape), dtype=self.param_dtype)
 
     def ones(self, shape):
-        return jnp.ones(shape, dtype=self.param_dtype)
+        return jnp.ones(self.lead + tuple(shape), dtype=self.param_dtype)
+
+    def constant(self, value, shape):
+        """``value`` broadcast to ``shape`` (plus the stacked dims)."""
+        return jnp.broadcast_to(jnp.asarray(value, self.param_dtype),
+                                self.lead + tuple(shape))
 
 
 def rms_norm(x: jax.Array, gamma: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -91,4 +115,5 @@ def embed(table: jax.Array, tokens: jax.Array) -> jax.Array:
 
 def unembed(x: jax.Array, table: jax.Array) -> jax.Array:
     """Project hidden states to vocabulary logits (f32)."""
-    return jnp.einsum("...d,vd->...v", x, table).astype(jnp.float32)
+    return jnp.einsum("...d,vd->...v", x, table,
+                      preferred_element_type=jnp.float32)

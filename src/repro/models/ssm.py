@@ -34,9 +34,9 @@ def init_mamba(init, d_model: int, d_state: int, d_conv: int,
         "dt_proj": init.normal((r, d_in), fan_in=r),
         "dt_bias": init.zeros((d_in,)),
         # S4D-real initialization: A = -(1..N), stored as log
-        "a_log": jnp.broadcast_to(
+        "a_log": init.constant(
             jnp.log(jnp.arange(1, d_state + 1, dtype=jnp.float32)),
-            (d_in, d_state)).astype(init.param_dtype),
+            (d_in, d_state)),
         "d_skip": init.ones((d_in,)),
         "out_proj": init.normal((d_in, d_model), fan_in=d_in),
     }

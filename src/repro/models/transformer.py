@@ -172,17 +172,16 @@ class LM:
         if not cfg.tie_embeddings:
             params["lm_head"] = init.normal(
                 (cfg.vocab_size, cfg.d_model), fan_in=cfg.d_model)
-        # decoder stack: stack n_rep copies per in-period position
+        # decoder stack: n_rep stacked copies per in-period position
         blocks = []
-        for j, spec in enumerate(self.specs):
-            reps = [self._init_layer(init, spec) for _ in range(self.n_rep)]
-            blocks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *reps))
+        for spec in self.specs:
+            with init.stacked(self.n_rep):
+                blocks.append(self._init_layer(init, spec))
         params["blocks"] = blocks
         if cfg.is_encdec:
-            enc_spec = LayerSpec("attn", False, False)
-            reps = [self._init_layer(init, enc_spec)
-                    for _ in range(cfg.n_encoder_layers)]
-            params["encoder"] = jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
+            with init.stacked(cfg.n_encoder_layers):
+                params["encoder"] = self._init_layer(
+                    init, LayerSpec("attn", False, False))
             params["enc_norm"] = init.ones((cfg.d_model,))
         if cfg.frontend_tokens and cfg.frontend_dim != cfg.d_model:
             params["frontend_proj"] = init.normal(

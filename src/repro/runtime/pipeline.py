@@ -63,12 +63,12 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh: Mesh,
         params_local = jax.tree.map(lambda t: t[0], params_local)
         stage_id = jax.lax.axis_index(axis)
         steps = mu + n_stages - 1
-        # pvary: the carry becomes device-varying after the first
-        # ppermute, so its initial value must be typed as varying too
-        # (jax < 0.5 has no explicit varying types: identity there)
-        pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-        buf = pvary(jnp.zeros_like(xs_local[0]), (axis,))
-        out = pvary(jnp.zeros_like(xs_local), (axis,))
+        # the carry becomes device-varying after the first ppermute, so
+        # its initial value must be typed as varying too
+        buf = jax.lax.pcast(jnp.zeros_like(xs_local[0]), (axis,),
+                            to="varying")
+        out = jax.lax.pcast(jnp.zeros_like(xs_local), (axis,),
+                            to="varying")
 
         def step(carry, t):
             buf, out = carry
@@ -96,10 +96,8 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh: Mesh,
         # broadcast ppermute would make it replicated)
         return out[None]
 
-    from jax.experimental.shard_map import shard_map
-
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    result = shard_map(
+    result = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(spec_params, P()),

@@ -1,9 +1,9 @@
 """Batched serving loop: request queue → slot-based continuous batching.
 
-Production shape in miniature: a fixed pool of ``slots`` (the batch
-dimension of the jitted decode step), requests admitted the moment a
-slot frees up, per-slot cache cursors (vectorized positions through
-the decode path), greedy decode until EOS/max-tokens, slot recycled.
+A fixed pool of ``slots`` (the batch dimension of the jitted decode
+step), requests admitted the moment a slot frees up, per-slot cache
+cursors (vectorized positions through the decode path), greedy decode
+until EOS/max-tokens, slot recycled.
 One jitted step serves the whole pool every iteration regardless of
 request boundaries — the invariant continuous batching exists to
 maintain.
@@ -22,7 +22,7 @@ import numpy as np
 
 from repro.models import LM
 
-__all__ = ["Request", "ServeLoop"]
+__all__ = ["Request", "ServeLoop", "decode_program"]
 
 
 @dataclass
@@ -33,13 +33,31 @@ class Request:
     eos_id: int = -1                    # -1: never stops early
     out: list = field(default_factory=list)
     done: bool = False
+    # decode-path logits [V] at the last prompt position (the ones the
+    # first generated token is taken from)
+    prompt_logits: np.ndarray | None = None
+
+
+def decode_program(model: LM, cache_sharding=None):
+    """The jitted serving step ``(params, cache, tokens, pos) -> (logits,
+    cache)``: positions are traced, so one program serves every step;
+    the cache is donated and kept on ``cache_sharding``."""
+    return jax.jit(model.decode_step, donate_argnums=(1,),
+                   out_shardings=(None, cache_sharding))
 
 
 class ServeLoop:
-    """Continuous-batching server over a reduced-config model."""
+    """Continuous-batching server.
+
+    The KV cache has the model's parameter dtype.  ``cache_sharding``
+    (a pytree of shardings matching ``model.init_cache``) places the
+    cache on a mesh and pins it there across steps; params arrive
+    already placed.  The cache is donated to each step, so one copy is
+    resident.
+    """
 
     def __init__(self, model: LM, params, *, slots: int = 4,
-                 max_len: int = 64) -> None:
+                 max_len: int = 64, cache_sharding=None) -> None:
         if any(s.kind != "attn" for s in model.specs):
             raise ValueError(
                 "continuous batching requires attention caches "
@@ -48,16 +66,14 @@ class ServeLoop:
         self.params = params
         self.slots = slots
         self.max_len = max_len
-        self.cache = model.init_cache(slots, max_len, dtype=jnp.float32)
+        self.cache = jax.jit(lambda: model.init_cache(slots, max_len),
+                             out_shardings=cache_sharding)()
         self.queue: deque[Request] = deque()
         self.active: list[Request | None] = [None] * slots
         # per-slot cursor: index the next token will be written at
         self.pos = np.zeros(slots, np.int32)
         self.tokens = np.zeros((slots, 1), np.int32)
-
-        self._step = jax.jit(
-            lambda params, cache, tokens, pos:
-            model.decode_step(params, cache, tokens, pos))
+        self._step = decode_program(model, cache_sharding)
 
     # ------------------------------------------------------------------ #
     def submit(self, req: Request) -> None:
@@ -81,6 +97,8 @@ class ServeLoop:
         if p + 1 < plen:                       # still prefilling
             self.tokens[s, 0] = req.prompt[p + 1]
         else:                                  # generating
+            if p + 1 == plen:
+                req.prompt_logits = logits.copy()  # not a view of the batch
             tok = int(np.argmax(logits))
             req.out.append(tok)
             self.tokens[s, 0] = tok

@@ -61,10 +61,7 @@ class TestHloAnalyzer:
 
         x = jnp.zeros((64, 64))
         c = jax.jit(f).lower(x, x).compile()
-        xla_cost = c.cost_analysis()
-        if isinstance(xla_cost, (list, tuple)):  # jax < 0.5
-            xla_cost = xla_cost[0]
-        xla_flops = xla_cost["flops"]
+        xla_flops = c.cost_analysis()["flops"]
         ours = analyze_hlo(c.as_text()).flops
         assert ours > 5 * xla_flops
 

@@ -1,8 +1,9 @@
 """Pipeline-parallel runner tests.
 
 The GPipe schedule needs multiple devices, so the numerical checks run
-in a subprocess with 4 host-platform devices (the main test process
+in a subprocess with 4 host-platform CPU devices (the main test process
 keeps its single real device, per the dry-run isolation rule)."""
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -10,14 +11,14 @@ from pathlib import Path
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 _PROGRAM = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 from repro.runtime.pipeline import pipeline_apply, stack_stage_params
 
-mesh = jax.make_mesh((4,), ("stage",))
+assert len(jax.devices("cpu")) == 4, jax.devices()
+mesh = make_mesh((4,), ("stage",))
 rng = np.random.default_rng(0)
 D, B, S_STAGES = 16, 8, 4
 
@@ -77,11 +78,17 @@ print("MB_OK")
 
 
 def test_pipeline_forward_backward_multi_device():
+    # the child never needs an accelerator: pin it to virtual CPU
+    # devices so it does not try to load the TPU runtime
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (SRC, env.get("PYTHONPATH")) if p)
     proc = subprocess.run(
         [sys.executable, "-c", _PROGRAM],
-        capture_output=True, text=True, timeout=480,
-        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
+        capture_output=True, text=True, timeout=480, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "FWD_OK" in proc.stdout
