@@ -32,7 +32,6 @@ import argparse
 import json
 import sys
 import time
-from collections import defaultdict
 from dataclasses import replace
 from pathlib import Path
 
@@ -56,39 +55,17 @@ def check(cond: bool, msg: str) -> None:
         raise SystemExit(f"chip_smoke: FAILED: {msg}")
 
 
-class CompileLog:
-    """Counts XLA compilations (or persistent-cache loads) per jitted
-    function, from JAX's monitoring events."""
-
-    def __init__(self) -> None:
-        import jax
-        self.count: dict[str, int] = defaultdict(int)
-        self.seconds: dict[str, float] = defaultdict(float)
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, secs: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            name = kw.get("fun_name", "?")
-            self.count[name] += 1
-            self.seconds[name] += secs
-
-    def _on_event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def of(self, fun: str) -> int:
-        """Compilations of the function named ``fun``."""
-        return self.count.get(f"jit({fun})", 0)
-
-    def report(self) -> None:
-        total = sum(self.count.values())
-        log(f"compiles: {total} programs, "
-            f"{sum(self.seconds.values()):.2f} s compiling, "
-            f"{self.cache_hits} persistent-cache hits")
-        for name in sorted(self.count, key=lambda n: -self.seconds[n]):
-            log(f"  {name}: {self.count[name]} x, {self.seconds[name]:.2f} s")
+def report_compiles() -> None:
+    """The programs JAX compiled or loaded from the persistent cache, as
+    ``repro.obs``'s compile counter has counted them."""
+    from repro.obs import METRICS, cache_loads, compiles
+    secs = METRICS.histograms.get("jax.compile_s")
+    log(f"compiles: {compiles()} programs compiled, {cache_loads()} "
+        f"loaded from the persistent cache, "
+        f"{secs.sum if secs else 0.0:.2f} s in all")
+    for key in sorted(METRICS.counters):
+        if key.startswith(("jax.compiles.", "jax.cache_loads.")):
+            log(f"  {key}: {METRICS.counters[key]}")
 
 
 def tree_bytes(tree) -> int:
@@ -169,27 +146,31 @@ def check_decode_vs_forward(loop, reqs, label: str) -> None:
                   f"request {r.rid}: decode's top-1 is not near forward's")
 
 
-def one_chip(seed: int, compiles: CompileLog) -> None:
+def one_chip(seed: int) -> None:
     from repro.configs import get_config
     from repro.launch.mesh import make_local_mesh
+    from repro.obs import cache_loads, compiles
 
     cfg = get_config("minitron_4b")
     log(describe(cfg))
     loop, done = serve(cfg, make_local_mesh(1, 1), seed=seed,
                        label="minitron_4b")
-    decode_compiles = compiles.of("decode_step")
-    log(f"[minitron_4b] decode program compiled {decode_compiles} x")
+    decode_compiles = (compiles("jit(decode_step)")
+                       + cache_loads("jit(decode_step)"))
+    log(f"[minitron_4b] decode program compiled or loaded "
+        f"{decode_compiles} x")
     check(decode_compiles == 1, "decode step compiled more than once")
     check_decode_vs_forward(loop, [done[0], done[len(done) - 1]],
                             "minitron_4b")
 
 
-def four_chips(devices, seed: int, compiles: CompileLog) -> None:
+def four_chips(devices, seed: int) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config
     from repro.launch.mesh import make_local_mesh
+    from repro.obs import cache_loads, compiles
 
     few = dict(n_requests=4, max_prompt=128, new_tokens=16, seed=seed)
     mesh4 = make_local_mesh(1, 4)
@@ -202,7 +183,8 @@ def four_chips(devices, seed: int, compiles: CompileLog) -> None:
         f"(total {total}, quarter {total // 4})")
     check(max(per_dev) <= 0.3 * total,
           "params are not sharded over the four devices")
-    check(compiles.of("decode_step") == 1, "decode compiled more than once")
+    check(compiles("jit(decode_step)") + cache_loads("jit(decode_step)")
+          == 1, "decode compiled more than once")
     del loop
 
     # float32 params and full-precision float32 matmuls: the comparison
@@ -252,13 +234,14 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
     log(f"device: {dev.platform} {dev.device_kind}, {len(devices)} visible; "
         f"jax {jax.__version__}; compile cache {enable_compile_cache()}")
-    compiles = CompileLog()
+    from repro.obs import count_compiles
+    count_compiles()
     t0 = time.perf_counter()
     if args.chips == 4:
-        four_chips(devices, args.seed, compiles)
+        four_chips(devices, args.seed)
     else:
-        one_chip(args.seed, compiles)
-    compiles.report()
+        one_chip(args.seed)
+    report_compiles()
     for d in devices[:args.chips]:
         stats = d.memory_stats() or {}
         log(f"{d}: peak_bytes_in_use {stats.get('peak_bytes_in_use')} of "

@@ -450,56 +450,59 @@ class LM:
         cfg = self.cfg
         new_cache = dict(cache)
         if spec.kind == "attn":
-            b = x.shape[0]
-            h = rms_norm(x, p["mixer"]["norm"], cfg.norm_eps)
-            q = jnp.einsum("bsd,de->bse", h, p["mixer"]["wq"].astype(x.dtype))
-            k = jnp.einsum("bsd,de->bse", h, p["mixer"]["wk"].astype(x.dtype))
-            v = jnp.einsum("bsd,de->bse", h, p["mixer"]["wv"].astype(x.dtype))
-            if cfg.qkv_bias and "bq" in p["mixer"]:
-                q = q + p["mixer"]["bq"].astype(x.dtype)
-                k = k + p["mixer"]["bk"].astype(x.dtype)
-                v = v + p["mixer"]["bv"].astype(x.dtype)
-            q = q.reshape(b, 1, cfg.n_heads, cfg.hd)
-            k = k.reshape(b, 1, cfg.n_kv_heads, cfg.hd)
-            v = v.reshape(b, 1, cfg.n_kv_heads, cfg.hd)
-            # pos: scalar (whole batch at one cursor) or [B] vector
-            # (continuous batching: per-slot cursors)
-            pos_vec = jnp.asarray(pos)
-            if pos_vec.ndim == 0:
-                positions = jnp.full((b, 1), pos_vec)
-                upd = lambda buf, val: jax.lax.dynamic_update_slice_in_dim(
-                    buf, val.astype(buf.dtype), pos, axis=1)
-            else:
-                positions = pos_vec[:, None]
-                upd = lambda buf, val: jax.vmap(
-                    lambda bb, vv, pp:
-                    jax.lax.dynamic_update_slice_in_dim(
-                        bb, vv.astype(bb.dtype), pp, axis=0)
-                )(buf, val, pos_vec)
-            q = apply_rope(q, cos_sin, positions)
-            k = apply_rope(k, cos_sin, positions)
-            if "k_scale" in cache:        # int8-quantized cache
-                kq, ks = _quantize_kv(k)
-                vq, vs = _quantize_kv(v)
-                kc = upd(cache["k"], kq)
-                vc = upd(cache["v"], vq)
-                ksc = upd(cache["k_scale"], ks)
-                vsc = upd(cache["v_scale"], vs)
-                k_deq = kc.astype(x.dtype) * ksc.astype(x.dtype)
-                v_deq = vc.astype(x.dtype) * vsc.astype(x.dtype)
-                o = attn.decode_attention(q, k_deq, v_deq, pos_vec + 1,
-                                          sliding_window=cfg.sliding_window)
-                new_cache.update({"k": kc, "v": vc,
-                                  "k_scale": ksc, "v_scale": vsc})
-            else:
-                kc = upd(cache["k"], k)
-                vc = upd(cache["v"], v)
-                o = attn.decode_attention(q, kc, vc, pos_vec + 1,
-                                          sliding_window=cfg.sliding_window)
-                new_cache.update({"k": kc, "v": vc})
-            o = o.reshape(b, 1, cfg.n_heads * cfg.hd)
-            x = x + jnp.einsum("bse,ed->bsd", o,
-                               p["mixer"]["wo"].astype(x.dtype))
+            with jax.named_scope("attn"):
+                b = x.shape[0]
+                h = rms_norm(x, p["mixer"]["norm"], cfg.norm_eps)
+                q = jnp.einsum("bsd,de->bse", h, p["mixer"]["wq"].astype(x.dtype))
+                k = jnp.einsum("bsd,de->bse", h, p["mixer"]["wk"].astype(x.dtype))
+                v = jnp.einsum("bsd,de->bse", h, p["mixer"]["wv"].astype(x.dtype))
+                if cfg.qkv_bias and "bq" in p["mixer"]:
+                    q = q + p["mixer"]["bq"].astype(x.dtype)
+                    k = k + p["mixer"]["bk"].astype(x.dtype)
+                    v = v + p["mixer"]["bv"].astype(x.dtype)
+                q = q.reshape(b, 1, cfg.n_heads, cfg.hd)
+                k = k.reshape(b, 1, cfg.n_kv_heads, cfg.hd)
+                v = v.reshape(b, 1, cfg.n_kv_heads, cfg.hd)
+                # pos: scalar (whole batch at one cursor) or [B] vector
+                # (continuous batching: per-slot cursors)
+                pos_vec = jnp.asarray(pos)
+                if pos_vec.ndim == 0:
+                    positions = jnp.full((b, 1), pos_vec)
+                    upd = lambda buf, val: jax.lax.dynamic_update_slice_in_dim(
+                        buf, val.astype(buf.dtype), pos, axis=1)
+                else:
+                    positions = pos_vec[:, None]
+                    upd = lambda buf, val: jax.vmap(
+                        lambda bb, vv, pp:
+                        jax.lax.dynamic_update_slice_in_dim(
+                            bb, vv.astype(bb.dtype), pp, axis=0)
+                    )(buf, val, pos_vec)
+                q = apply_rope(q, cos_sin, positions)
+                k = apply_rope(k, cos_sin, positions)
+                if "k_scale" in cache:        # int8-quantized cache
+                    kq, ks = _quantize_kv(k)
+                    vq, vs = _quantize_kv(v)
+                    with jax.named_scope("kv_write"):
+                        kc = upd(cache["k"], kq)
+                        vc = upd(cache["v"], vq)
+                        ksc = upd(cache["k_scale"], ks)
+                        vsc = upd(cache["v_scale"], vs)
+                    k_deq = kc.astype(x.dtype) * ksc.astype(x.dtype)
+                    v_deq = vc.astype(x.dtype) * vsc.astype(x.dtype)
+                    o = attn.decode_attention(q, k_deq, v_deq, pos_vec + 1,
+                                              sliding_window=cfg.sliding_window)
+                    new_cache.update({"k": kc, "v": vc,
+                                      "k_scale": ksc, "v_scale": vsc})
+                else:
+                    with jax.named_scope("kv_write"):
+                        kc = upd(cache["k"], k)
+                        vc = upd(cache["v"], v)
+                    o = attn.decode_attention(q, kc, vc, pos_vec + 1,
+                                              sliding_window=cfg.sliding_window)
+                    new_cache.update({"k": kc, "v": vc})
+                o = o.reshape(b, 1, cfg.n_heads * cfg.hd)
+                x = x + jnp.einsum("bse,ed->bsd", o,
+                                   p["mixer"]["wo"].astype(x.dtype))
         elif spec.kind == "mamba":
             h = rms_norm(x, p["mixer"]["norm"], cfg.norm_eps)
             inner = {k2: cache[k2] for k2 in ("conv", "ssm")}
@@ -517,7 +520,8 @@ class LM:
             new_cache.update(inner)
         if spec.cross and memory is not None:
             x = x + self._cross_attn(p["cross"], x, memory)
-        y, _ = self._ffn(p, spec, x)
+        with jax.named_scope("ffn"):
+            y, _ = self._ffn(p, spec, x)
         return x + y, new_cache
 
     def decode_step(self, params, cache, tokens, pos, memory=None):
@@ -526,6 +530,11 @@ class LM:
         tokens: [B, 1] int32; pos: scalar int (current cache length).
         ``memory``: optional [B, M, d] cross-attention memory (VLM
         frontend / encoder output), already projected/encoded.
+
+        Named scopes (op metadata only, read from a device trace):
+        ``layers`` (the scan over blocks), ``attn`` (the attention half
+        of a block) with ``kv_write`` (the cache updates) inside it,
+        ``ffn``, and ``unembed`` (final norm and logits).
         """
         cfg = self.cfg
         x = embed(params["embed"], tokens).astype(self.param_dtype)
@@ -538,12 +547,15 @@ class LM:
                 x, c2 = self._layer_step(lp, spec, x, c, memory, cos_sin,
                                          pos)
                 return x, c2
-            x, nc = jax.lax.scan(body, x, (params["blocks"][j], cache[j]))
+            with jax.named_scope("layers"):
+                x, nc = jax.lax.scan(body, x,
+                                     (params["blocks"][j], cache[j]))
             new_caches.append(nc)
 
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        table = params.get("lm_head", params["embed"])
-        return unembed(x, table), new_caches
+        with jax.named_scope("unembed"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            table = params.get("lm_head", params["embed"])
+            return unembed(x, table), new_caches
 
     def encode_memory(self, params, frontend):
         """Prepare cross-attention memory once per request batch."""

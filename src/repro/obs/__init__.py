@@ -13,6 +13,9 @@ simulator (:mod:`repro.sim`), scenario runner and service loop
   (``repro.core.counters`` is its counter facet), with the
   snapshot/delta/merge protocol that ships per-worker metrics back
   through ``SweepPoint`` picklably;
+* :mod:`repro.obs.compiles` — a compile counter: JAX's programs
+  compiled and loaded from the persistent cache, per function, counted
+  into ``METRICS`` from JAX's monitoring events;
 * :mod:`repro.obs.export` — Chrome trace-event JSON
   (``chrome://tracing`` / Perfetto) with wall and virtual clock
   domains on separate ``pid``\\ s, and the :class:`JsonlSink` event
@@ -30,6 +33,7 @@ import logging
 import sys
 from dataclasses import dataclass
 
+from .compiles import cache_loads, compiles, count_compiles, programs
 from .export import (
     JsonlSink,
     service_virtual_events,
@@ -67,9 +71,13 @@ __all__ = [
     "Span",
     "Tracer",
     "activate",
+    "cache_loads",
+    "compiles",
+    "count_compiles",
     "current_tracer",
     "percentile",
     "percentiles",
+    "programs",
     "service_virtual_events",
     "setup_logging",
     "sim_proc_events",
@@ -91,20 +99,22 @@ class ObsConfig:
     records stream there as they happen.  ``trace_path`` writes the
     Chrome trace at the end of the run.  ``probe_spans`` opts into
     per-probe spans in the incremental engine (off by default; see
-    :class:`~repro.obs.tracer.Tracer`).
+    :class:`~repro.obs.tracer.Tracer`).  ``profiler`` mirrors the spans
+    into the JAX profiler's trace as well.
     """
 
     enabled: bool = False
     sink: str | None = None
     trace_path: str | None = None
     probe_spans: bool = False
+    profiler: bool = False
 
     def make_tracer(self) -> Tracer | None:
         """A fresh tracer when ``enabled``, else ``None`` (feed to
         :func:`activate`, which treats ``None`` as a passthrough)."""
         if not self.enabled:
             return None
-        return Tracer(probe_spans=self.probe_spans)
+        return Tracer(probe_spans=self.probe_spans, profiler=self.profiler)
 
 
 def setup_logging(level: int = logging.INFO, *,

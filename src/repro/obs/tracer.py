@@ -23,6 +23,14 @@ list, never feed back into control flow — makespans and service
 traces are bit-identical with tracing on or off (asserted by
 ``tests/test_obs.py``).
 
+``Tracer(profiler=True)`` also mirrors every span into the JAX
+profiler's trace: each span enters ``jax.profiler.TraceAnnotation``
+(``StepTraceAnnotation`` when it carries a ``step_num`` attribute) and
+hands it its attributes as stats when it closes.  Under
+``jax.profiler.trace`` the spans then sit in the ``.xplane.pb`` on the
+thread that opened them, on the clock of the device's events.  ``jax``
+is imported only by such a tracer, never by the planner's spans.
+
 Worker processes of the parallel k' sweep install a fresh tracer per
 sweep-point task and ship their finished spans back picklably inside
 the ``SweepPoint``; the parent splices them into its own tracer, so
@@ -81,12 +89,20 @@ class Tracer:
     default even when tracing: probes fire tens of thousands of times
     per sweep and the per-span cost would break the ≤10 % enabled
     overhead budget; flip it on for a microscope view of one run.
+
+    ``profiler`` mirrors each span into the JAX profiler's trace (see
+    the module docstring); the in-memory ``spans`` list is kept either
+    way.
     """
 
-    def __init__(self, *, probe_spans: bool = False,
+    def __init__(self, *, probe_spans: bool = False, profiler: bool = False,
                  tid: str | None = None) -> None:
         self.spans: list[Span] = []
         self.probe_spans = probe_spans
+        self._annotations = None
+        if profiler:
+            from jax.profiler import StepTraceAnnotation, TraceAnnotation
+            self._annotations = (TraceAnnotation, StepTraceAnnotation)
         self._default_tid = tid
         self._local = threading.local()
         self._lock = threading.Lock()
@@ -110,12 +126,25 @@ class Tracer:
     def span(self, name: str, **attrs):
         stack = self._stack()
         depth = len(stack)
+        ann = None
+        if self._annotations is not None:
+            plain, step = self._annotations
+            ann = (step(name, step_num=attrs["step_num"])
+                   if "step_num" in attrs else plain(name))
+            ann.__enter__()
         open_span = _OpenSpan(name, time.perf_counter(), attrs)
         stack.append(open_span)
         try:
             yield open_span
         finally:
             t1 = time.perf_counter()
+            if ann is not None:
+                if open_span.attrs:
+                    stats = {k: _stat(v) for k, v in open_span.attrs.items()
+                             if k != "step_num"}
+                    if stats:
+                        ann.set_metadata(**stats)
+                ann.__exit__(None, None, None)
             stack.pop()
             sp = Span(name=name, ts=open_span.t0,
                       dur=t1 - open_span.t0, tid=self._tid(),
@@ -141,6 +170,17 @@ class Tracer:
         """Spans slowest-first (the ``tools/trace_view.py`` table)."""
         out = sorted(self.spans, key=lambda s: -s.dur)
         return out if n is None else out[:n]
+
+
+def _stat(value):
+    """A span attribute as a profiler stat: numbers and strings as they
+    are, a sequence as its items joined by spaces (the profiler's
+    metadata takes ``,``, ``#`` and ``=`` as separators)."""
+    if isinstance(value, (list, tuple)):
+        return " ".join(str(v) for v in value)
+    if isinstance(value, (bool, int, float, str)):
+        return value
+    return str(value)
 
 
 # ------------------------------------------------------------------ #

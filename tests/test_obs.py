@@ -363,3 +363,139 @@ class TestSerialization:
         assert ServiceReport.from_dict(sd).metrics == {}
         assert ServiceReport.from_dict(sd).plan_latency_percentiles \
             is None
+
+
+# ---------------------------------------------------------------------- #
+# spans mirrored into the JAX profiler's trace; the compile counter
+# ---------------------------------------------------------------------- #
+def _profiled(body, tmp_path):
+    """Run ``body`` under ``jax.profiler.trace``; the host events of the
+    thread that ran it, as {name: [(start_ns, end_ns, stats)]}."""
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        body()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs: dict = {}
+            for e in line.events:
+                evs.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+            if "outer" in evs:
+                return evs
+    raise AssertionError("no host line holds the spans")
+
+
+class TestProfilerMirror:
+    def test_spans_nest_on_the_host_plane_with_their_stats(self, tmp_path):
+        tr = Tracer(profiler=True)
+
+        def body():
+            with activate(tr):
+                for step in range(2):
+                    with trace_span("outer", step_num=step) as sp:
+                        with trace_span("inner", k=3):
+                            pass
+                        sp.attrs.update(n=5, rids=[4, 7])
+
+        evs = _profiled(body, tmp_path)
+        assert len(evs["outer"]) == len(evs["inner"]) == 2
+        for (o0, o1, ostats), (i0, i1, istats) in zip(evs["outer"],
+                                                      evs["inner"]):
+            assert o0 <= i0 <= i1 <= o1
+            assert istats["k"] == 3
+            assert ostats["n"] == 5 and ostats["rids"] == "4 7"
+        assert [s[2]["step_num"] for s in evs["outer"]] == [0, 1]
+        # the in-memory spans are kept as well
+        assert [s.name for s in tr.spans] == ["inner", "outer"] * 2
+        assert tr.spans[1].attrs["rids"] == [4, 7]
+
+    def test_obs_config_makes_a_mirroring_tracer(self):
+        tr = ObsConfig(enabled=True, profiler=True).make_tracer()
+        assert tr._annotations is not None
+        assert ObsConfig(enabled=True).make_tracer()._annotations is None
+
+    def test_without_a_tracer_the_null_span_records_nothing(self):
+        from repro.obs.tracer import _NULL_SPAN
+        idle = Tracer(profiler=True)        # made, never activated
+        assert not tracing_active()
+        assert trace_span("x", a=1) is _NULL_SPAN
+        with trace_span("x") as sp:
+            sp.attrs["b"] = 2
+        assert idle.spans == []
+
+
+class TestCompileCounter:
+    def test_one_shape_compiles_once_a_second_shape_again(self):
+        import jax
+        import numpy as np
+        from repro.obs import cache_loads, compiles, count_compiles
+
+        count_compiles()
+        count_compiles()                      # idempotent: one listener
+
+        def counted_twice(x):
+            return x * 2 + 1
+
+        f = jax.jit(counted_twice)
+        name = "jit(counted_twice)"
+        before = compiles() + cache_loads()
+        f(np.ones(3, np.float32))
+        f(np.zeros(3, np.float32))
+        assert compiles(name) + cache_loads(name) == 1
+        f(np.ones(4, np.float32))
+        assert compiles(name) + cache_loads(name) == 2
+        assert compiles() + cache_loads() - before == 2
+        assert METRICS.histograms["jax.compile_s"].count >= 2
+
+    def test_programs_counts_compiles_and_cache_loads_together(self):
+        import jax
+        import numpy as np
+        from repro.obs import cache_loads, compiles, count_compiles, programs
+
+        count_compiles()
+        before = programs()
+        assert before == compiles() + cache_loads()
+
+        def counted_in_all(x):
+            return x - 7
+
+        jax.jit(counted_in_all)(np.ones(6, np.float32))
+        assert programs() - before == 1 == compiles("jit(counted_in_all)")
+        assert programs() == compiles() + cache_loads()
+
+    def test_a_cache_load_is_not_a_compile(self, tmp_path):
+        import jax
+        import numpy as np
+        from jax.experimental.compilation_cache import compilation_cache
+        from repro.obs import cache_loads, compiles, count_compiles
+
+        count_compiles()
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+        prev = {k: getattr(jax.config, k) for k in keys}
+        try:
+            jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+            compilation_cache.reset_cache()
+
+            def loaded_once(x):
+                return x * 3 - 1
+
+            name = "jit(loaded_once)"
+            x = np.ones(5, np.float32)
+            jax.jit(loaded_once)(x)
+            assert (compiles(name), cache_loads(name)) == (1, 0)
+            jax.clear_caches()
+            jax.jit(loaded_once)(x)
+            assert (compiles(name), cache_loads(name)) == (1, 1)
+        finally:
+            for k, v in prev.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
