@@ -23,6 +23,7 @@ __all__ = [
     "param_sharding_rules",
     "tree_shardings",
     "batch_sharding",
+    "cache_sharding_rules",
     "cache_shardings",
     "make_shard_act",
     "pick_policy",
@@ -200,32 +201,63 @@ def frontend_sharding(mesh: Mesh, batch: int | None = None):
     return NamedSharding(mesh, P(dp, None, None))
 
 
-def cache_shardings(cache_tree, mesh: Mesh, batch: int):
-    """Decode caches: batch over data axes when divisible, else the
-    sequence (KV) dim over "model"."""
+_KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+
+
+def cache_sharding_rules(cache_tree, mesh: Mesh, batch: int):
+    """Pytree of PartitionSpec matching the decode cache ``cache_tree``
+    (of ShapeDtypeStruct or arrays), as ``LM.init_cache`` lays it out.
+
+    * axis 1 (slots) over the data axes when the batch divides them;
+    * attention ``k``/``v`` [rep, B, S, hkv, hd] and their int8 scales
+      ``k_scale``/``v_scale`` [rep, B, S, hkv, 1]: a sequence longer
+      than 1024 tokens over "model"; else the KV-head axis 3 over a
+      "model" axis longer than one (a one-chip mesh keeps its layout)
+      when the heads divide it.  The ``wk``/``wv`` rules split
+      the K/V projections by column over "model", so each model rank
+      computes a contiguous block of KV heads; keeping the cache on the
+      same axis lets each rank write and read only its own heads, where
+      a replicated cache is gathered whole every step;
+    * Mamba ``ssm`` [rep, B, d_in, N] and ``conv`` [rep, B, K-1, d_in]:
+      axis 2 over "model" when it divides; RWKV ``last_x`` [rep, B, d]
+      and ``state`` [rep, B, H, hd, hd]: batch only.
+
+    Attention leaves are found by their key, not their rank (RWKV's
+    ``state`` is 5-D too); any dimension that does not divide its axis
+    stays unsharded.
+    """
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp_size = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
     batch_ok = batch % dp_size == 0
+    msize = mesh.shape["model"]
 
-    def spec(leaf):
+    def spec(path, leaf):
         shape = leaf.shape
         nd = len(shape)
         axes = [None] * nd
-        # layouts: attn k/v [rep, B, S, hkv, hd]; mamba conv [rep, B, K, d_in];
-        # mamba ssm [rep, B, d_in, N]; rwkv last_x [rep, B, d];
-        # rwkv state [rep, B, H, hd, hd]
         if batch_ok and nd >= 2:
             axes[1] = dp
         if nd == 5 and shape[2] > 1024:
-            # attention KV cache: shard the long sequence over "model"
-            if shape[2] % mesh.shape["model"] == 0:
+            # a long KV cache: the sequence over "model"
+            if shape[2] % msize == 0:
                 axes[2] = "model"
-        elif nd == 4 and shape[2] % mesh.shape["model"] == 0:
+        elif path[-1].key in _KV_LEAVES and msize > 1:
+            axes[3] = "model"          # KV heads, as wk/wv produce them
+        elif nd == 4 and shape[2] % msize == 0:
             axes[2] = "model"          # mamba ssm d_in over model
         return _shard_if_divisible(mesh, shape, *axes)
 
-    return jax.tree.map(
-        lambda l: NamedSharding(mesh, spec(l)), cache_tree)
+    return jax.tree_util.tree_map_with_path(spec, cache_tree)
+
+
+def cache_shardings(cache_tree, mesh: Mesh, batch: int):
+    """Decode caches placed on ``mesh`` by ``cache_sharding_rules``:
+    slots over the data axes, attention K/V by KV head over "model"
+    (the axis the column-split ``wk``/``wv`` produce them on), a cache
+    longer than 1024 tokens by sequence instead."""
+    specs = cache_sharding_rules(cache_tree, mesh, batch)
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def make_shard_act(mesh: Mesh, policy: str = "fsdp_tp"):

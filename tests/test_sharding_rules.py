@@ -7,9 +7,9 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.launch.mesh import make_local_mesh
-from repro.launch.sharding import param_sharding_rules
+from repro.launch.sharding import cache_sharding_rules, param_sharding_rules
 from repro.models import LM
 
 
@@ -89,3 +89,49 @@ def test_fsdp_batch_sharding_uses_model_axis():
     assert tuple(sh.spec)[0] == ("data", "model")
     sh2 = batch_sharding(mesh, 256, policy="fsdp_tp")
     assert tuple(sh2.spec)[0] in ("data", ("data",))  # P normalizes 1-tuples
+
+
+KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+
+# (mesh, published or smoke config, cache length)
+CACHE_CASES = {
+    "heads_over_model": (FakeMesh({"data": 1, "model": 4}), get_config, 768),
+    "model_of_one": (FakeMesh({"data": 1, "model": 1}), get_config, 768),
+    "smoke_heads": (FakeMesh({"data": 1, "model": 4}), get_smoke_config, 768),
+    "long_cache": (FakeMesh({"data": 1, "model": 4}), get_config, 2048),
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(CACHE_CASES))
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_cache_sharding_rules(arch, case, kv_dtype):
+    """Attention K/V (and their int8 scales) lie by KV head on "model"
+    when the heads divide it, by sequence when the cache is longer than
+    1024 tokens, unsharded on "model" on a mesh whose model axis is 1;
+    Mamba and RWKV leaves keep their rank-based specs."""
+    mesh, get, max_len = CACHE_CASES[case]
+    slots, msize = 4, mesh.shape["model"]
+    model = LM(get(arch), kv_dtype=kv_dtype)
+    shapes = jax.eval_shape(lambda: model.init_cache(slots, max_len))
+    specs = cache_sharding_rules(shapes, mesh, slots)
+    flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
+    spec_leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
+    assert len(flat) == len(spec_leaves)
+    for (path, leaf), spec in zip(flat, spec_leaves):
+        key, shape = path[-1].key, leaf.shape
+        want = [None] * len(shape)
+        want[1] = "data"                              # slots over data
+        if key in KV_LEAVES:
+            hkv = shape[3]
+            if max_len > 1024:
+                want[2] = "model"
+            elif msize > 1 and hkv % msize == 0:
+                want[3] = "model"
+            if case == "heads_over_model":
+                assert want[3] == "model", (arch, hkv)
+        elif key in ("conv", "ssm") and shape[2] % msize == 0:
+            want[2] = "model"
+        else:
+            assert key in ("conv", "ssm", "last_x", "state"), key
+        assert spec == P(*want), (arch, key, shape, spec)

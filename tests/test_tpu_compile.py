@@ -111,3 +111,24 @@ def test_llama3_decode_sharded_four_chips(topo):
     assert total > 15e9                           # published widths
     assert 0.24 * total <= per_device <= 0.26 * total
     assert _device_bytes(compiled.memory_analysis()) <= HBM_BYTES
+
+
+def test_granite_decode_keeps_cache_sharded_by_head(topo):
+    """The four-chip serving step keeps each chip's KV heads where the
+    column-split K/V projections produce them: no all-gather of the
+    whole stacked cache, 2 of 8 KV heads a chip."""
+    cfg = get_config("granite_8b")
+    slots, max_len = 32, 768
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices[:4])
+    model, _, cache_sh = serve_layout(cfg, mesh, slots=slots,
+                                      max_len=max_len)
+    compiled, _ = _decode_program(cfg, mesh, slots=slots, max_len=max_len)
+    whole = (f"bf16[{cfg.n_layers},{slots},{max_len},{cfg.n_kv_heads},"
+             f"{cfg.hd}]")
+    gathers = [line for line in compiled.as_text().splitlines()
+               if "all-gather" in line and whole in line.split("=")[1]]
+    assert not gathers, gathers[:2]
+    cache = jax.eval_shape(lambda: model.init_cache(slots, max_len))
+    for leaf, sh in zip(jax.tree.leaves(cache), jax.tree.leaves(cache_sh)):
+        assert sh.shard_shape(leaf.shape)[3] == cfg.n_kv_heads // 4
+    assert _device_bytes(compiled.memory_analysis()) < 6e9
