@@ -1,11 +1,13 @@
-"""Plain reference of the served model, and its lower-precision control.
+"""Architecture ``dense_gqa``: the plain reference of a dense decoder
+with grouped-query attention, its lower-precision control, and the work
+it needs, counted from its shapes.
 
-A dense decoder with grouped-query attention, written out in
-``jax.numpy`` with no cache, no batching and no kernels, in float32
-with full-precision matmuls.  It follows the equations the program
-implements; where those depart from the published model the
-configuration file lists the departure (``departures``), and the
-reference keeps the program's form so that the two can be compared:
+The reference is written out in ``jax.numpy`` with no cache, no
+batching and no kernels, in float32 with full-precision matmuls.  It
+follows the equations the program implements; where those depart from
+the published model the configuration file lists the departure
+(``departures``), and the reference keeps the program's form so that
+the two can be compared:
 
     x      = E[token]
     per layer:
@@ -25,26 +27,28 @@ file names (``weights``), on the device, without the program.
 The control is the same model computed one precision lower than the
 configuration states: weights rounded to float8 (e4m3, one scale per
 output column) and activations and matmul inputs in bfloat16.
+
+The counts (``Shapes``) are the operations and bytes the mathematics
+asks for, not what a given program does: a step that reads the whole
+KV cache where only the valid entries are needed, or unembeds a
+position whose logits nobody uses, does more than is counted here.  So
+a program that stops wasting work moves the shares computed from these
+counts, and the counts themselves stay put.
 """
 from __future__ import annotations
+
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-F32 = jnp.float32
-DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+from ..numerics import DTYPES, F32, fp8, weight_seed
+from ..work import DTYPE_BYTES
 
 
 def _hd(m: dict) -> int:
     return m.get("head_dim") or m["d_model"] // m["n_heads"]
-
-
-def weight_seed(seed: int) -> np.uint32:
-    """The number the weights' key is made from: the run's seed modulo
-    2**32, as an unsigned 32-bit integer, so that one compiled program
-    draws the weights of every seed."""
-    return np.uint32(seed % 2**32)
 
 
 def weight_recipe(m: dict, dtype, seed: int) -> dict:
@@ -117,15 +121,6 @@ def make_weights(m: dict, dtype: str, seed: int, mesh):
                    out_shardings=shardings(shapes, mesh))(key)
 
 
-def _fp8(w, axis: int = -2):
-    """float8 e4m3 with one absmax scale per output column (the input
-    dimension ``axis`` is reduced)."""
-    w = w.astype(F32)
-    scale = jnp.max(jnp.abs(w), axis=axis, keepdims=True) / 448.0
-    scale = jnp.maximum(scale, 1e-12)
-    return (w / scale).astype(jnp.float8_e4m3fn).astype(F32) * scale
-
-
 def _rope_tables(hd: int, n: int, theta: float):
     inv = 1.0 / (theta ** (np.arange(0, hd, 2) / hd))
     ang = np.einsum("p,f->pf", np.arange(n), inv)
@@ -141,7 +136,7 @@ def hidden(m: dict, p: dict, tokens, *, control: bool = False):
     cos, sin = _rope_tables(hd, s, m.get("rope_theta", 10000.0))
 
     def w(x):
-        return (_fp8(x) if control else x.astype(F32)).astype(act)
+        return (fp8(x) if control else x.astype(F32)).astype(act)
 
     def mm(x, wt):
         return jnp.einsum("bsd,de->bse", x, wt,
@@ -182,7 +177,7 @@ def hidden(m: dict, p: dict, tokens, *, control: bool = False):
     layers = {k: p[k] for k in ("attn_norm", "wq", "wk", "wv", "wo",
                                 "ffn_norm", "w_gate", "w_up", "w_down")}
     x = p["embed"][tokens]
-    x = (_fp8(x, -1) if control else x.astype(F32)).astype(act)
+    x = (fp8(x, -1) if control else x.astype(F32)).astype(act)
     x, _ = jax.lax.scan(layer, x, layers)
     return norm(x, p["final_norm"])
 
@@ -198,10 +193,86 @@ def logits(p: dict, x, *, control: bool = False, chunks: int = 8):
     tc = t.reshape(chunks, v // chunks, d)
 
     def one(_, tab):
-        tab = (_fp8(tab, -1).astype(jnp.bfloat16) if control
+        tab = (fp8(tab, -1).astype(jnp.bfloat16) if control
                else tab.astype(F32))
         return None, jnp.einsum("md,vd->mv", x.astype(tab.dtype), tab,
                                 preferred_element_type=F32)
 
     _, out = jax.lax.scan(one, None, tc)
     return out.transpose(1, 0, 2).reshape(x.shape[0], v)
+
+
+@dataclass(frozen=True)
+class Shapes:
+    """The work counts, from the ``"model"`` block of a configuration
+    file: the field names of the program's ``ModelConfig``
+    (``n_layers``, ``d_model``, ``n_heads``, ``n_kv_heads``, ``d_ff``,
+    ``vocab_size``, ``head_dim``, ``tie_embeddings``, ``qkv_bias``).
+    The FFN is SwiGLU (three matrices)."""
+    n_layers: int
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    d_ff: int
+    vocab_size: int
+    head_dim: int
+    tie_embeddings: bool
+    qkv_bias: bool
+    dtype_bytes: int
+
+    @classmethod
+    def of(cls, model: dict, dtype: str) -> "Shapes":
+        return cls(model["n_layers"], model["d_model"], model["n_heads"],
+                   model["n_kv_heads"], model["d_ff"], model["vocab_size"],
+                   _hd(model), bool(model.get("tie_embeddings", False)),
+                   bool(model.get("qkv_bias", False)), DTYPE_BYTES[dtype])
+
+    # -- parameters --------------------------------------------------- #
+    def layer_matmul_params(self) -> int:
+        d, hd = self.d_model, self.head_dim
+        attn = d * self.n_heads * hd * 2 + d * self.n_kv_heads * hd * 2
+        return attn + 3 * d * self.d_ff
+
+    def layer_params(self) -> int:
+        bias = ((self.n_heads + 2 * self.n_kv_heads) * self.head_dim
+                if self.qkv_bias else 0)
+        return self.layer_matmul_params() + bias + 2 * self.d_model
+
+    def table_params(self) -> int:
+        return self.vocab_size * self.d_model
+
+    def total_params(self) -> int:
+        tables = self.table_params() * (1 if self.tie_embeddings else 2)
+        return (tables + self.n_layers * self.layer_params()
+                + self.d_model)
+
+    def param_bytes(self) -> int:
+        return self.total_params() * self.dtype_bytes
+
+    def kv_bytes_per_token(self) -> int:
+        return (self.n_layers * 2 * self.n_kv_heads * self.head_dim
+                * self.dtype_bytes)
+
+    # -- one decode step ---------------------------------------------- #
+    def step_flops(self, step) -> int:
+        """A ``records.StepRecord``'s tokens, one per admitted slot,
+        attending over the slots' valid cache entries, of which
+        ``n_logits`` positions need logits."""
+        matmul = 2 * step.n_tokens * self.n_layers * self.layer_matmul_params()
+        attn = 4 * self.n_layers * self.n_heads * self.head_dim * step.sum_ctx
+        unembed = 2 * step.n_logits * self.d_model * self.vocab_size
+        return matmul + attn + unembed
+
+    def step_bytes(self, step) -> int:
+        """Weights read once (of the embedding table only the rows of
+        the step's tokens), the valid KV entries read and the new ones
+        written.  The unembedding table is read only where some
+        position needs logits."""
+        if step.n_tokens == 0:
+            return 0
+        b = self.dtype_bytes
+        weights = (self.n_layers * self.layer_params() + self.d_model) * b
+        rows = step.n_tokens * self.d_model * b
+        table = self.table_params() * b if step.n_logits else 0
+        kv = (step.sum_ctx + step.n_tokens) * self.kv_bytes_per_token()
+        return weights + rows + table + kv

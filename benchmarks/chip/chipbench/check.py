@@ -2,9 +2,9 @@
 
 Once the window has closed and the program's state is freed, a sample
 of the finished requests, drawn from the seed and always holding the
-one with the most tokens, is run through the float32 reference: each
-prompt followed by the tokens the server generated, in one causal
-pass.  Two numbers are read:
+one with the most tokens, is run through the float32 reference of the
+configuration's architecture (``archs/<name>.py``): each prompt
+followed by the tokens the server generated, in one causal pass.  Two numbers are read:
 
 * ``gap``: over every generated token of the sample, the widest gap
   by which the token's reference logit lies below the reference's
@@ -16,7 +16,7 @@ pass.  Two numbers are read:
   (``Request.prompt_logits``) and the reference's there.
 
 The control puts the reference one precision lower in the program's
-place (``reference.hidden(control=True)``): at the same positions the
+place (``hidden(control=True)``): at the same positions the
 token it puts first is read under the float32 reference in the same
 way, and its logits at the last prompt position against the
 reference's.  ``verdict`` judges its readings (``as_control``) under
@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import reference as ref
-
 
 def sample(records, k: int, seed: int) -> list[int]:
     """Indices of ``k`` complete requests: the longest one, and the
@@ -48,9 +46,10 @@ def sample(records, k: int, seed: int) -> list[int]:
     return [longest] + [rest[j] for j in sorted(pick)]
 
 
-def readings(model: dict, dtype: str, seed: int, mesh, served: list,
+def readings(ref, model: dict, dtype: str, seed: int, mesh, served: list,
              *, max_len: int, max_out: int, control: bool = False) -> dict:
-    """``served``: (prompt, generated tokens, prompt_logits) of each
+    """``ref``: the architecture's module (``archs.load``);
+    ``served``: (prompt, generated tokens, prompt_logits) of each
     sampled request.  Returns the program's two numbers and, with
     ``control``, the control's."""
     t0 = time.perf_counter()
@@ -70,7 +69,8 @@ def readings(model: dict, dtype: str, seed: int, mesh, served: list,
         valid[i, :n] = True
 
     with jax.default_matmul_precision("highest"):
-        hid = jax.jit(lambda p, t: ref.hidden(model, p, t))(params, tokens)
+        hid = jax.jit(lambda p, t: ref.hidden(model, p, t, control=False))(
+            params, tokens)
         hid_c = (jax.jit(lambda p, t: ref.hidden(model, p, t, control=True))(
             params, tokens) if control else None)
         jax.block_until_ready((hid, hid_c))
@@ -78,7 +78,7 @@ def readings(model: dict, dtype: str, seed: int, mesh, served: list,
 
         @jax.jit
         def one(p, h, hc, pos, tok, valid):
-            lg = ref.logits(p, h[pos])                       # [M, V]
+            lg = ref.logits(p, h[pos], control=False)        # [M, V]
             best = lg.max(-1)
             picked = jnp.take_along_axis(lg, tok[:, None], 1)[:, 0]
             gap = jnp.max(jnp.where(valid, best - picked, 0.0))

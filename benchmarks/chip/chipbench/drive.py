@@ -90,14 +90,14 @@ def drive(loop, plan: list[Planned], requests: list, seconds: float, *,
                 j = queued.popleft()
                 recs[j].admit, recs[j].admit_step = ts, k
                 inflight.append(j)
-            sum_ctx = sum(k - recs[j].admit_step + 1 for j in inflight)
+            ctx_lens = tuple(k - recs[j].admit_step + 1 for j in inflight)
             n_logits = 0
             for j in inflight:
                 new = len(requests[j].out) - recs[j].n_out
                 if new > 0:
                     n_logits += new
                     recs[j].stamps.extend([te] * new)
-            steps.append(StepRecord(ts, te, len(inflight), sum_ctx, n_logits))
+            steps.append(StepRecord(ts, te, ctx_lens, n_logits))
             done = {index[id(r)] for r in finished if id(r) in index}
             if done:
                 inflight = [j for j in inflight if j not in done]

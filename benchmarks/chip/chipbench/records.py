@@ -45,9 +45,20 @@ class RequestRecord:
 class StepRecord:
     start: float
     end: float
-    n_tokens: int       # tokens the step took in, one per admitted slot
-    sum_ctx: int        # valid cache entries those tokens attend over
+    # the valid cache entries each admitted slot's token attends over,
+    # its own included, in the order the slots' requests were admitted
+    ctx_lens: tuple[int, ...]
     n_logits: int       # positions whose logits gave a token
+
+    @property
+    def n_tokens(self) -> int:
+        """Tokens the step took in, one per admitted slot."""
+        return len(self.ctx_lens)
+
+    @property
+    def sum_ctx(self) -> int:
+        """Valid cache entries the step's tokens attend over, in all."""
+        return sum(self.ctx_lens)
 
 
 def percentile(values, q: float) -> float:

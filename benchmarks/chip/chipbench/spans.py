@@ -1,16 +1,18 @@
 """From a profiler trace to the serving loop's own spans and the decode
 program's time by named scope.
 
-``extract`` reads from the same ``.xplane.pb`` as ``xplane.extract``:
+``extract`` reads from the same profile as ``xplane.extract``:
 
 * on the host thread that drives the loop, the program's ``serve.*``
   spans (``repro.runtime.serve_loop``, mirrored into the profiler's
   trace by ``repro.obs.Tracer(profiler=True)``) with their stats, and
   the harness's ``bench.*`` spans;
 * on each device, every op with the path of named scopes it was traced
-  under (``layers``, ``attn``, ``kv_write``, ``ffn``, ``unembed``, from
-  ``models/transformer.LM.decode_step``), and the executions of the
-  programs.
+  under, and the executions of the programs.
+
+Which named scopes ``reduce`` gives time to is data: a configuration's
+``"scopes"`` list, by default ``SCOPES``, those of
+``models/transformer.LM.decode_step``.
 
 A TPU trace's op events carry no ``op_name`` (their stats are the
 device offset and duration), so an op's scope path comes from the
@@ -22,11 +24,11 @@ times are nanoseconds on the trace's one clock.
 from __future__ import annotations
 
 import re
+from bisect import bisect_left, bisect_right
 from collections import defaultdict
 from dataclasses import dataclass, field
-from pathlib import Path
 
-from .xplane import _union, self_times
+from .xplane import _labels, _union, self_times
 
 SCOPES = ("layers", "attn", "kv_write", "ffn", "unembed")
 STEP = "serve.step"
@@ -62,18 +64,17 @@ def instruction(op: str) -> str:
     return op.split(" = ")[0].strip().lstrip("%")
 
 
-def scope_of(path: str) -> str:
-    """The innermost of ``SCOPES`` in an ``op_name`` path, or ``""``."""
+def scope_of(path: str, scopes=SCOPES) -> str:
+    """The innermost of ``scopes`` in an ``op_name`` path, or ``""``."""
     inner = ""
     for part in path.split("/"):
-        if part in SCOPES:
+        if part in scopes:
             inner = part
     return inner
 
 
-def extract(path: Path, op_names: dict[str, str] | None = None) -> SpanTrace:
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(str(path))
+def extract(data, op_names: dict[str, str] | None = None) -> SpanTrace:
+    """``data``: a ``jax.profiler.ProfileData``."""
     st = SpanTrace()
     op_names = op_names or {}
     for plane in data.planes:
@@ -125,18 +126,32 @@ def _program_end(st: SpanTrace, lo: float, hi: float,
     return max(ends, default=None)
 
 
-def reduce(st: SpanTrace, program: str = "decode_step") -> dict:
+def _overlapping(iv: list[tuple[float, float]], starts: list[float],
+                 a: float, b: float):
+    """The intervals of ``iv`` (sorted, not overlapping; ``starts`` their
+    starts) that overlap ``(a, b)``."""
+    i = max(bisect_right(starts, a) - 1, 0)
+    while i < len(iv) and iv[i][0] < b:
+        if iv[i][1] > a:
+            yield iv[i]
+        i += 1
+
+
+def reduce(st: SpanTrace, program: str = "decode_step",
+           scopes=SCOPES) -> dict:
     """Per step of the serving loop, in ms and averaged over the traced
     steps: ``launch_ms`` (start of the step to the end of its launch),
     ``pull_ms`` (end of the step's decode program on the latest chip to
     the end of the pull; the pull's earlier part waits on the device),
-    ``pick_ms``; the decode program's device self time by innermost
-    scope, averaged over the chips too (``attn_ms`` is ``attn`` less
-    ``kv_write``, ``layer_cache_ms`` is ``kv_write`` and ``layers``
-    outside ``attn``/``ffn``, ``unscoped_ms`` is the ops in none; an op
-    with no scope of its own takes that of the op it runs inside);
-    ``program_ms``, the decode program's device time; ``prefill_share``
-    (%) from the steps' stats; and the device's idle time, its share
+    ``pick_ms``; ``prefill_share`` (%) from the steps' stats; and, where
+    the trace holds device ops, the decode program's device self time by
+    innermost scope of ``scopes``, averaged over the chips too
+    (``scope_ms``, ``""`` for the ops in none; an op with no scope of
+    its own takes that of the op it runs inside), and of the default
+    scopes ``attn_ms`` (``attn`` less ``kv_write``), ``ffn_ms``,
+    ``unembed_ms``, ``layer_cache_ms`` (``kv_write`` and ``layers``
+    outside ``attn``/``ffn``) and ``unscoped_ms``; ``program_ms``, the
+    decode program's device time; and the device's idle time, its share
     inside the steps, and how it divides among the innermost host spans
     it falls in.  Empty where the trace has no step with its four
     children."""
@@ -164,35 +179,46 @@ def reduce(st: SpanTrace, program: str = "decode_step") -> dict:
     out["prefill_share"] = 100.0 * pre / (pre + dec) if pre + dec else None
 
     devices = sorted(d for d, ops in st.ops.items() if ops)
+    if not devices:
+        return out
     by_scope: dict[str, float] = defaultdict(float)
     prog = 0.0
-    idle_by_span: dict[str, float] = defaultdict(float)
     idle = idle_in_steps = 0.0
+    pieces: list[tuple[float, float]] = []
     step_iv = [(x["start"], x["end"]) for x in steps]
+    step_starts = [a for a, _ in step_iv]
+    edges_host = sorted({t for _, s, d, _ in st.host for t in (s, s + d)})
     for dev in devices:
-        runs = [(s, s + d) for name, s, d in st.modules.get(dev, [])
-                if program in name and lo <= s <= hi]
+        runs = sorted((s, s + d) for name, s, d in st.modules.get(dev, [])
+                      if program in name and lo <= s <= hi)
+        run_starts = [a for a, _ in runs]
         prog += sum(b - a for a, b in runs)
         inside = [(f"{i}|{scope}", max(s, a), min(s + d, b) - max(s, a))
-                  for i, (name, s, d, scope) in enumerate(_inherit(st.ops[dev]))
-                  for a, b in runs if s < b and s + d > a]
+                  for i, (name, s, d, scope)
+                  in enumerate(_inherit(st.ops[dev], scopes))
+                  for a, b in _overlapping(runs, run_starts, s, s + d)]
         for key, t in self_times(inside).items():
             by_scope[key.split("|", 1)[1]] += t
         merged = _union([(max(s, lo), min(s + d, hi))
                          for _, s, d, _ in st.ops[dev] if s + d > lo and s < hi])
         edges = [lo] + [x for ab in merged for x in ab] + [hi]
-        gaps = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
-        for a, b in gaps:
+        for a, b in zip(edges[::2], edges[1::2]):
+            if b <= a:
+                continue
             idle += b - a
-            idle_in_steps += sum(max(0.0, min(b, e) - max(a, s))
-                                 for s, e in step_iv)
-            cuts = sorted({a, b} | {t for _, s, d, _ in st.host
-                                    for t in (s, s + d) if a < t < b})
-            for x, y in zip(cuts, cuts[1:]):
-                idle_by_span[_innermost(st.host, (x + y) / 2)] += y - x
-    k = len(devices) or 1
-    per = 1e-6 / k / n
+            idle_in_steps += sum(min(b, e) - max(a, s) for s, e in
+                                 _overlapping(step_iv, step_starts, a, b))
+            cuts = [a] + edges_host[bisect_right(edges_host, a):
+                                    bisect_left(edges_host, b)] + [b]
+            pieces.extend(zip(cuts, cuts[1:]))
+    label = _labels([(name, s, d) for name, s, d, _ in st.host],
+                    [(x + y) / 2 for x, y in pieces])
+    idle_by_span: dict[str, float] = defaultdict(float)
+    for x, y in pieces:
+        idle_by_span[label[(x + y) / 2]] += y - x
+    per = 1e-6 / len(devices) / n
     out["program_ms"] = prog * per
+    out["scope_ms"] = {k: by_scope[k] * per for k in ("", *scopes)}
     out["attn_ms"] = by_scope["attn"] * per
     out["ffn_ms"] = by_scope["ffn"] * per
     out["unembed_ms"] = by_scope["unembed"] * per
@@ -205,7 +231,7 @@ def reduce(st: SpanTrace, program: str = "decode_step") -> dict:
     return out
 
 
-def _inherit(ops):
+def _inherit(ops, scopes):
     """Each op with its innermost scope; an op that has none of its own
     takes that of the innermost op whose interval holds it: XLA's loops
     run their bodies inside the loop's own event, and fusions it clones
@@ -214,16 +240,7 @@ def _inherit(ops):
     for name, s, d, path in sorted(ops, key=lambda e: (e[1], -e[2])):
         while stack and stack[-1][0] <= s:
             stack.pop()
-        scope = scope_of(path) or (stack[-1][1] if stack else "")
+        scope = scope_of(path, scopes) or (stack[-1][1] if stack else "")
         stack.append((s + d, scope))
         out.append((name, s, d, scope))
     return out
-
-
-def _innermost(host, t: float) -> str:
-    """The innermost host span that covers time ``t``."""
-    best, best_d = "host idle (no span)", float("inf")
-    for name, s, d, _ in host:
-        if s <= t <= s + d and d < best_d:
-            best, best_d = name, d
-    return best

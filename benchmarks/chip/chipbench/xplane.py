@@ -1,10 +1,10 @@
 """From a profiler trace to device busy time, idle gaps and program time.
 
-``extract`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into
-a small ``Trace``: per device, the intervals of its operations and of
-its program executions; on the host, the spans of the thread that
-drives the loop (the harness's ``bench.*`` spans and what runs inside
-them).  ``reduce`` works on a ``Trace`` alone, so it is tested on a
+``extract`` reads the ``.xplane.pb`` that ``jax.profiler`` writes
+(``load``) into a small ``Trace``: per device, the intervals of its
+operations and of its program executions; on the host, the spans of
+the thread that drives the loop (the harness's ``bench.*`` spans and
+what runs inside them).  ``reduce`` works on a ``Trace`` alone, so it is tested on a
 synthetic one with known intervals.
 
 The window is the trace's own: from the start of the first
@@ -32,9 +32,14 @@ class Trace:
     planes: dict[str, dict[str, int]] = field(default_factory=dict)
 
 
-def extract(path: Path) -> Trace:
+def load(path: Path):
+    """The profile in an ``.xplane.pb`` file."""
     from jax.profiler import ProfileData
-    data = ProfileData.from_file(str(path))
+    return ProfileData.from_file(str(path))
+
+
+def extract(data) -> Trace:
+    """``data``: a ``jax.profiler.ProfileData`` (``load``)."""
     tr = Trace()
     host_lines = []
     for plane in data.planes:

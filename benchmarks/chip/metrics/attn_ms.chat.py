@@ -1,0 +1,8 @@
+"""Attention's device time in the decode program, ms a step: self time
+of the ops under the named scope ``attn`` less those under
+``kv_write``, mean over the chips and the traced steps
+(``spans.reduce``).  Moves ``itl_p99_ms``."""
+
+
+def read(run):
+    return run.spans.get("attn_ms")

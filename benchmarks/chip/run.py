@@ -5,9 +5,11 @@
         --seed 1234 --seconds 45 --trace 0
 
 Run from the root of a checkout on a machine with the chips the cell
-asks for.  Everything about a cell is data, found by name from
-``BENCHMARK.json``: its configuration (``configs/<config>.json``), its
-traffic mix (``traffic/<traffic>.json``), its per-layer metrics
+asks for.  Everything about a cell is found by name from
+``BENCHMARK.json``: its configuration (``configs/<config>.json``), the
+architecture that names (``chipbench/archs/<architecture>.py``: the
+reference and the work counts), its traffic mix
+(``traffic/<traffic>.json``), its per-layer metrics
 (``metrics/<name>.py``) and the limits of its correctness check
 (``limits/<workload>.json``).
 
@@ -24,10 +26,12 @@ One run, in one process:
    sample of what it served with the float32 reference;
 4. prints the result as the last line of standard output: with
    ``--trace 0`` the cell's end-to-end metrics, with ``--trace 1`` its
-   per-layer metrics read from a profiler trace of part of the window.
+   per-layer metrics read from a profiler trace of part of the window,
+   in which the program's own tracer puts its spans.
 
 Without a TPU, or with fewer chips than the cell asks for, it prints no
-result and exits 2.
+result and exits 2; with an unknown architecture it exits before it
+looks for one.
 """
 from __future__ import annotations
 
@@ -43,6 +47,7 @@ import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from contextlib import nullcontext  # noqa: E402
 from pathlib import Path  # noqa: E402
 from types import SimpleNamespace  # noqa: E402
 
@@ -51,8 +56,9 @@ ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
 
-from chipbench import check, records, reference, traffic, work  # noqa: E402
+from chipbench import archs, check, records, spans, traffic, xplane  # noqa: E402
 from chipbench.drive import drive  # noqa: E402
+from chipbench.numerics import weight_seed  # noqa: E402
 from chipbench.peaks import peak_for  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
@@ -72,13 +78,14 @@ def load_cell(name: str, root: Path = ROOT) -> SimpleNamespace:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = json.loads((root / conf["file"]).read_text())
 
     def applies(metric):
         return name in metric.get("workloads", [name])
 
     return SimpleNamespace(
-        name=name, chips=cell["chips"],
-        config=json.loads((root / conf["file"]).read_text()),
+        name=name, chips=cell["chips"], config=config,
+        arch=archs.load(config["architecture"]),
         mix=traffic.load(HERE / "traffic" / f"{cell['traffic']}.json"),
         limits=check.load_limits(HERE / "limits" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
@@ -166,10 +173,12 @@ def reader(name: str):
 
 class Profiler:
     """``jax.profiler`` into a temporary directory, host tracing of
-    annotations only (no Python function tracing)."""
+    annotations only (no Python function tracing).  ``op_names`` and
+    ``scopes`` as ``spans.extract`` and ``spans.reduce`` take them."""
 
-    def __init__(self) -> None:
+    def __init__(self, op_names: dict[str, str], scopes) -> None:
         import jax
+        self.op_names, self.scopes = op_names, scopes
         self.dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         self.opts = jax.profiler.ProfileOptions()
         self.opts.python_tracer_level = 0
@@ -183,15 +192,19 @@ class Profiler:
         import jax
         jax.profiler.stop_trace()
 
-    def reduce(self) -> dict:
-        from chipbench import xplane
+    def reduce(self) -> tuple[dict, dict]:
+        """(``xplane.reduce`` of the trace, ``spans.reduce`` of the
+        program's spans in it), each ``{}`` where there is no trace;
+        removes the trace."""
         paths = sorted(Path(self.dir).rglob("*.xplane.pb"))
         try:
             if not paths:
-                return {}
-            tr = xplane.extract(paths[-1])
+                return {}, {}
+            data = xplane.load(paths[-1])
+            tr = xplane.extract(data)
             log(f"trace planes: {tr.planes}")
-            return xplane.reduce(tr)
+            return xplane.reduce(tr), spans.reduce(
+                spans.extract(data, self.op_names), scopes=self.scopes)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -216,8 +229,7 @@ def build_server(cfg, mesh, *, slots: int, max_len: int, seed: int,
 
     model, param_sh, cache_sh = serve_layout(
         cfg, mesh, slots=slots, max_len=max_len, param_dtype=param_dtype)
-    params = jax.jit(model.init, out_shardings=param_sh)(
-        reference.weight_seed(seed))
+    params = jax.jit(model.init, out_shardings=param_sh)(weight_seed(seed))
     return ServeLoop(model, params, slots=slots, max_len=max_len,
                      cache_sharding=cache_sh)
 
@@ -262,6 +274,7 @@ def run_cell(cell, devices, *, seed: int, seconds: float, trace: bool,
     for the devices' kind."""
     import jax
     from repro.launch.mesh import make_local_mesh
+    from repro.obs import Tracer, activate
 
     use_compile_cache()
     compiles = Compiles()
@@ -269,15 +282,16 @@ def run_cell(cell, devices, *, seed: int, seconds: float, trace: bool,
     server = mix["server"]
     kind = devices[0].device_kind
     peak = peak or peak_for(kind)
-    shapes = work.Shapes.of(conf["model"], conf["dtype"])
+    shapes = cell.arch.Shapes.of(conf["model"], conf["dtype"])
 
     # -- set-up: server, warm-up, the schedule -------------------------- #
     loop, Request, mesh_shape = start_server(cell, devices, seed, compiles)
     plan = traffic.schedule(mix, seconds, seed, conf["model"]["vocab_size"])
     reqs = [Request(i, p.prompt, max_new_tokens=p.max_new_tokens)
             for i, p in enumerate(plan)]
+    profiler = (Profiler(spans.hlo_op_names(loop.decode_hlo()),
+                         conf.get("scopes", spans.SCOPES)) if trace else None)
     loaded_before = compiles.total()
-    profiler = Profiler() if trace else None
     # the profiler covers the window's last seconds: stopping it stalls
     # the host while it collects the device's events, and the stall then
     # falls in the drain, after the last arrival
@@ -288,8 +302,9 @@ def run_cell(cell, devices, *, seed: int, seconds: float, trace: bool,
     # -- the window and the drain -------------------------------------- #
     pauses = GcPauses()
     use0 = resource.getrusage(resource.RUSAGE_SELF)
-    run = drive(loop, plan, reqs, seconds, trace=span, profiler=profiler,
-                annotate=jax.profiler.TraceAnnotation if trace else None)
+    with activate(Tracer(profiler=True)) if trace else nullcontext():
+        run = drive(loop, plan, reqs, seconds, trace=span, profiler=profiler,
+                    annotate=jax.profiler.TraceAnnotation if trace else None)
     use1 = resource.getrusage(resource.RUSAGE_SELF)
     pauses.close()
     in_window = compiles.total() - loaded_before
@@ -327,7 +342,8 @@ def run_cell(cell, devices, *, seed: int, seconds: float, trace: bool,
     gc.collect()
     t0 = time.perf_counter()
     numbers = check.readings(
-        conf["model"], conf["dtype"], seed, make_local_mesh(*mesh_shape),
+        cell.arch, conf["model"], conf["dtype"], seed,
+        make_local_mesh(*mesh_shape),
         served, max_len=server["max_len"], max_out=mix["output"]["max"],
         control=control) if served else {}
     phases = numbers.pop("phases_s", {})
@@ -348,11 +364,12 @@ def run_cell(cell, devices, *, seed: int, seconds: float, trace: bool,
               "count": len(devices), "memory_peak_bytes": int(peak_bytes)}
     metrics, out = {}, {}
     ctx = SimpleNamespace(records=recs, steps=run.steps, end=run.end,
-                          shapes=shapes, peak=peak, chips=len(devices),
-                          seconds=seconds, traced_steps=run.traced_steps,
-                          trace={})
+                          config=conf, shapes=shapes, peak=peak,
+                          chips=len(devices), seconds=seconds,
+                          traced_steps=run.traced_steps, trace={}, spans={})
     if trace:
-        ctx.trace = profiler.reduce()
+        ctx.trace, ctx.spans = profiler.reduce()
+        log(f"program spans: {json.dumps(ctx.spans)}")
         tr = ctx.trace
         if tr:
             device.update(busy_s=tr["busy_s"], window_s=tr["window_s"])

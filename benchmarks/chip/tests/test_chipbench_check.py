@@ -20,7 +20,7 @@ BENCH = HERE.parent
 ROOT = BENCH.parents[1]
 sys.path.insert(0, str(BENCH))
 
-from chipbench import reference as ref  # noqa: E402
+from chipbench.archs import dense_gqa as ref  # noqa: E402
 
 TINY = json.loads((HERE / "data" / "tiny.json").read_text())["model"]
 LIMIT = 0.1     # the tiny cell's limit on ``gap`` (data/run_tiny.py)
@@ -86,21 +86,39 @@ def test_control_departs_from_the_reference():
     assert 0.05 < err < 5.0
 
 
-def run_tiny(tmp_path, fault: str, chips: int = 1) -> dict:
+def run_tiny(cache: Path, fault: str, chips: int = 1,
+             config: str = "tiny.json") -> tuple[dict, dict, str]:
+    """``data/run_tiny.py``'s run: (result line, the check's readings,
+    standard error)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     if chips > 1:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
                             f"platform_device_count={chips}").strip()
     p = subprocess.run(
-        [sys.executable, str(HERE / "data" / "run_tiny.py"),
-         str(tmp_path / "cache"), fault, str(chips)],
+        [sys.executable, str(HERE / "data" / "run_tiny.py"), str(cache),
+         fault, str(chips), config],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
-    return json.loads(p.stdout.strip().splitlines()[-1])
+    *_, readings, result = p.stdout.strip().splitlines()
+    return json.loads(result), json.loads(readings)["readings"], p.stderr
 
 
-def test_sound_run_is_correct_and_the_control_is_not(tmp_path):
-    r = run_tiny(tmp_path, "none")
+@pytest.fixture(scope="module")
+def tiny(tmp_path_factory):
+    """``tiny(fault, chips=1)``: the tiny cell's run with ``fault``
+    planted, made once for every test that asks for it."""
+    cache = tmp_path_factory.mktemp("tiny") / "cache"
+    runs: dict = {}
+
+    def get(fault: str, chips: int = 1) -> tuple[dict, dict, str]:
+        if (fault, chips) not in runs:
+            runs[fault, chips] = run_tiny(cache, fault, chips)
+        return runs[fault, chips]
+    return get
+
+
+def test_sound_run_is_correct_and_the_control_is_not(tiny):
+    r, _, _ = tiny("none")
     assert r["correct"] is True
     assert r["failed"] == 0 and r["attempted"] > 0
     assert set(r["metrics"]) == {"ttft_p90_s", "itl_p99_ms", "setup_s"}
@@ -110,22 +128,64 @@ def test_sound_run_is_correct_and_the_control_is_not(tmp_path):
     assert r["checks"]["gap"]["value"] < LIMIT
     # the float8 control, judged in the program's place by the cell's
     # own limits, is not correct
-    c = run_tiny(tmp_path, "control")
+    c, _, _ = tiny("control")
     assert c["correct"] is False
     assert c["checks"]["gap"]["value"] == c["readings"]["control_gap"]
     assert c["readings"]["gap"] < LIMIT < c["readings"]["control_gap"]
 
 
 @pytest.mark.parametrize("fault", ["token", "state", "batch"])
-def test_planted_fault_is_not_correct(tmp_path, fault):
-    r = run_tiny(tmp_path, fault)
+def test_planted_fault_is_not_correct(tiny, fault):
+    r, _, _ = tiny(fault)
     assert r["correct"] is False
     assert r["checks"]["gap"]["value"] > LIMIT
 
 
-def test_sharded_run_is_correct_and_without_the_exchange_is_not(tmp_path):
-    sound = run_tiny(tmp_path, "none", chips=4)
+def test_sharded_run_is_correct_and_without_the_exchange_is_not(tiny):
+    sound, _, _ = tiny("none", 4)
     assert sound["correct"] is True and sound["device"]["count"] == 4
-    broken = run_tiny(tmp_path, "exchange", chips=4)
+    broken, _, _ = tiny("exchange", 4)
     assert broken["correct"] is False
     assert broken["checks"]["gap"]["value"] > LIMIT
+
+
+# (correct, gap, logit_err) of each tiny run on the CPU, as the harness
+# read them with its dense reference in one module of its own: reaching
+# the reference through the architecture's module changes no arithmetic
+DENSE_READINGS = {
+    ("none", 1): (True, 0.004175901412963867, 0.03570368140935898),
+    ("control", 1): (False, 0.004175901412963867, 0.03570368140935898),
+    ("token", 1): (False, 4.964898109436035, 10000.016677677631),
+    ("state", 1): (False, 5.185284614562988, 5.122796297073364),
+    ("batch", 1): (False, 5.270834922790527, 5.934383153915405),
+    ("none", 4): (True, 0.004175424575805664, 0.04033172130584717),
+    ("exchange", 4): (False, 4.752098083496094, 4.474851608276367),
+}
+
+
+@pytest.mark.parametrize("fault,chips", list(DENSE_READINGS))
+def test_verdict_and_readings_are_the_dense_modules(tiny, fault, chips):
+    correct, gap, logit_err = DENSE_READINGS[fault, chips]
+    r, readings, _ = tiny(fault, chips)
+    assert r["correct"] is correct
+    assert readings["gap"] == pytest.approx(gap, rel=1e-5)
+    assert readings["logit_err"] == pytest.approx(logit_err, rel=1e-5)
+
+
+def test_an_architecture_enters_as_new_files(tmp_path):
+    """``data/dense_window.py`` and ``data/tiny_window.json`` alone add an
+    architecture: the harness checks the windowed program against that
+    module's reference and counts with its ``Shapes``.  Named
+    ``dense_gqa``, the same configuration is not correct."""
+    r, readings, err = run_tiny(tmp_path / "cache", "none", 1,
+                                "tiny_window.json")
+    assert r["correct"] is True and readings["gap"] < LIMIT
+    assert "dense_window: hidden" in err.splitlines()
+    assert "dense_window: Shapes.of" in err.splitlines()
+    conf = json.loads((HERE / "data" / "tiny_window.json").read_text())
+    conf["architecture"] = "dense_gqa"
+    (tmp_path / "as_dense.json").write_text(json.dumps(conf))
+    d, readings, err = run_tiny(tmp_path / "cache", "none", 1,
+                                str(tmp_path / "as_dense.json"))
+    assert d["correct"] is False and readings["gap"] > LIMIT
+    assert "dense_window" not in err

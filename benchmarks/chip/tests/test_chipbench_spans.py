@@ -1,6 +1,7 @@
 """The serving loop's spans and the decode program's scopes, read from a
-trace: ``chipbench.spans`` on a synthetic trace with known intervals,
-and ``trace_serve.py`` end to end on the CPU at a tiny size."""
+trace: ``chipbench.spans`` and the span readers on a synthetic trace
+with known intervals, and ``run.py``'s traced run end to end on the CPU
+at a tiny size."""
 from __future__ import annotations
 
 import importlib.util
@@ -139,20 +140,63 @@ def reader(name: str):
                                   "queue_wait_share.chat", "step_mfu.chat",
                                   "step_roofline.chat"])
 def test_existing_readers_ignore_the_span_readings(name):
-    from chipbench import peaks, work
+    from chipbench import peaks
+    from chipbench.archs import dense_gqa
     recs = [RequestRecord(0.0, 4, 3, sent=0.0, admit=0.01, admit_step=0,
                           stamps=[0.1, 0.2, 0.3])]
-    steps = [StepRecord(0.01 * k, 0.01 * k + 0.008, 1, k + 1, 1)
+    steps = [StepRecord(0.01 * k, 0.01 * k + 0.008, (k + 1,), 1)
              for k in range(6)]
     conf = json.loads((BENCH / "configs" / "minitron_4b.json").read_text())
     ctx = SimpleNamespace(
         records=recs, steps=steps, end=0.4, chips=1, seconds=0.4,
-        shapes=work.Shapes.of(conf["model"], conf["dtype"]),
+        config=conf, shapes=dense_gqa.Shapes.of(conf["model"], conf["dtype"]),
         peak=peaks.peak_for("TPU v5 lite"), traced_steps=(0, 6),
-        trace=xplane.reduce(_xplane_trace()))
+        trace=xplane.reduce(_xplane_trace()), spans={})
     plain = reader(name)(ctx)
     ctx.spans = spans.reduce(synthetic())
     assert plain is not None and reader(name)(ctx) == plain
+
+
+SPAN_METRICS = {"launch_ms.chat": "launch_ms", "pull_ms.chat": "pull_ms",
+                "pick_ms.chat": "pick_ms", "attn_ms.chat": "attn_ms",
+                "ffn_ms.chat": "ffn_ms", "unembed_ms.chat": "unembed_ms",
+                "layer_cache_ms.chat": "layer_cache_ms",
+                "unscoped_ms.chat": "unscoped_ms"}
+
+
+@pytest.mark.parametrize("name", list(SPAN_METRICS))
+def test_span_readers_read_the_span_readings(name):
+    r = spans.reduce(synthetic(4))
+    assert reader(name)(SimpleNamespace(spans=r)) == r[SPAN_METRICS[name]]
+    assert r[SPAN_METRICS[name]] > 0
+    assert reader(name)(SimpleNamespace(spans={})) is None
+
+
+def test_scopes_are_data():
+    """A scope that the configuration names gets its ops' time; ops under
+    a scope it does not name go to the scope that holds them."""
+    st = synthetic()
+    for dev, ops in st.ops.items():
+        st.ops[dev] = [(n, s, d, p.replace("/ffn/", "/moe/"))
+                       for n, s, d, p in ops]
+    default = spans.reduce(st)
+    moe = spans.reduce(st, scopes=spans.SCOPES + ("moe",))
+    ms = 1e-6
+    assert default["ffn_ms"] == 0 and moe["ffn_ms"] == 0
+    assert moe["scope_ms"]["moe"] == pytest.approx(60 * ms)
+    assert "moe" not in default["scope_ms"]
+    # without the scope, its ops fall to the layer loop that holds them
+    assert default["layer_cache_ms"] == pytest.approx((20 + 25 + 60) * ms)
+    assert moe["layer_cache_ms"] == pytest.approx((20 + 25) * ms)
+    assert sum(moe["scope_ms"].values()) == pytest.approx(moe["program_ms"])
+
+
+def test_a_trace_without_device_ops_reads_no_scopes():
+    st = synthetic()
+    st.ops, st.modules = {}, {}
+    r = spans.reduce(st)
+    assert r["launch_ms"] > 0 and r["pull_ms"] is None
+    assert "attn_ms" not in r and "scope_ms" not in r
 
 
 def _xplane_trace() -> xplane.Trace:
@@ -163,25 +207,27 @@ def _xplane_trace() -> xplane.Trace:
         host=[(n, s, d) for n, s, d, _ in st.host])
 
 
-def test_traced_run_with_the_programs_spans_on_the_cpu(tmp_path):
-    """``trace_serve.py``'s run on the tiny cell: the spans reach the
-    profiler's trace on the driving thread, with the step stats."""
+def test_traced_run_with_the_programs_spans_on_the_cpu(tmp_path, capsys):
+    """``run.run_cell(..., trace=True)`` on the tiny cell: the program's
+    spans reach the profiler's trace on the driving thread, with the
+    step stats, and the span readers read them."""
     import jax
     import run
     import run_tiny
-    import trace_serve
     run.CACHE_DIR = tmp_path / "cache"
     cell = run_tiny.tiny_cell()
-    cell.per_layer = [{"name": "queue_wait_share.chat", "unit": "%"}]
-    drive, profiler = run.drive, run.Profiler
-    result, r = trace_serve.traced_run(
-        cell, jax.devices()[:1], seed=2**31 + 17, seconds=3.0,
-        out=tmp_path / "out", peak=run_tiny.PEAK)
-    assert (run.drive, run.Profiler) == (drive, profiler)   # restored
+    cell.per_layer = [{"name": n, "unit": "ms"} for n in SPAN_METRICS] + [
+        {"name": "queue_wait_share.chat", "unit": "%"}]
+    result, _ = run.run_cell(cell, jax.devices()[:1], seed=2**31 + 17,
+                             seconds=3.0, trace=True, peak=run_tiny.PEAK)
     assert result["correct"]
     assert "queue_wait_share.chat" in result["metrics"]
+    line = [x for x in capsys.readouterr().err.splitlines()
+            if x.startswith("program spans: ")]
+    r = json.loads(line[-1].removeprefix("program spans: "))
     assert r["steps"] > 0 and r["launch_ms"] > 0 and r["pick_ms"] > 0
     assert 0 < r["prefill_share"] < 100
-    assert (tmp_path / "out" / "trace.xplane.pb.gz").exists()
-    assert "op_name=" in (tmp_path / "out" / "decode.hlo.txt").read_text()
-    assert json.loads((tmp_path / "out" / "readings.json").read_text()) == r
+    # the CPU's trace holds no device plane, so only the host spans read
+    got = result["metrics"]
+    assert got["launch_ms.chat"]["value"] == r["launch_ms"]
+    assert got["pick_ms.chat"]["value"] == r["pick_ms"]

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -20,14 +21,19 @@ ROOT = BENCH.parents[1]
 sys.path.insert(0, str(BENCH))
 
 from chipbench import records, traffic, work, xplane  # noqa: E402
+from chipbench.archs import dense_gqa  # noqa: E402
 from chipbench.drive import drive  # noqa: E402
 from chipbench.peaks import peak_for  # noqa: E402
-from chipbench.records import RequestRecord  # noqa: E402
+from chipbench.records import RequestRecord, StepRecord  # noqa: E402
 
 
-def shapes(name: str) -> work.Shapes:
+def shapes(name: str) -> dense_gqa.Shapes:
     conf = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    return work.Shapes.of(conf["model"], conf["dtype"])
+    return dense_gqa.Shapes.of(conf["model"], conf["dtype"])
+
+
+def step(ctx_lens, n_logits):
+    return StepRecord(0.0, 0.0, tuple(ctx_lens), n_logits)
 
 
 # -- work counts and peaks ------------------------------------------------ #
@@ -49,13 +55,57 @@ def test_step_work_by_hand():
     s = shapes("minitron_4b")
     d, v, nl = 3072, 256_000, 32
     # one token at position 0, no logits: matmuls and one KV entry
-    assert s.step_flops(1, 1, 0) == 2 * nl * (110_106_624 - 2 * d) \
+    assert s.step_flops(step([1], 0)) == 2 * nl * (110_106_624 - 2 * d) \
         + 4 * nl * 24 * 128
-    assert s.step_flops(0, 0, 1) == 2 * d * v
+    assert s.step_flops(step([], 1)) == 2 * d * v
     weights = (nl * 110_106_624 + d) * 2
-    assert s.step_bytes(1, 1, 0) == weights + d * 2 + 2 * 131_072
-    assert s.step_bytes(1, 1, 1) == s.step_bytes(1, 1, 0) + v * d * 2
-    assert s.step_bytes(0, 0, 0) == 0
+    assert s.step_bytes(step([1], 0)) == weights + d * 2 + 2 * 131_072
+    assert s.step_bytes(step([1], 1)) == s.step_bytes(step([1], 0)) + v * d * 2
+    assert s.step_bytes(step([], 0)) == 0
+
+
+def dense_counts_of_totals(s, n_tokens: int, sum_ctx: int, n_logits: int):
+    """The dense counts written out from a step's three totals."""
+    hd, d, nl = s.head_dim, s.d_model, s.n_layers
+    matmul = d * (s.n_heads + s.n_kv_heads) * hd * 2 + 3 * d * s.d_ff
+    flops = (2 * n_tokens * nl * matmul + 4 * nl * s.n_heads * hd * sum_ctx
+             + 2 * n_logits * d * s.vocab_size)
+    if n_tokens == 0:
+        return flops, 0
+    b = s.dtype_bytes
+    kv = nl * 2 * s.n_kv_heads * hd * b
+    nbytes = ((nl * (matmul + 2 * d) + d) * b + n_tokens * d * b
+              + (s.vocab_size * d * b if n_logits else 0)
+              + (sum_ctx + n_tokens) * kv)
+    return flops, nbytes
+
+
+@pytest.mark.parametrize("name", ["minitron_4b", "granite_8b"])
+def test_dense_counts_of_a_step_record_are_those_of_its_totals(name):
+    s = shapes(name)
+    rng = np.random.default_rng(2**31 + 3)
+    for _ in range(300):
+        lens = rng.integers(1, 769, size=rng.integers(0, 33))
+        n_logits = int(rng.integers(0, len(lens) + 1))
+        rec = step(lens.tolist(), n_logits)
+        assert rec.n_tokens == len(lens) and rec.sum_ctx == lens.sum()
+        assert (s.step_flops(rec), s.step_bytes(rec)) == \
+            dense_counts_of_totals(s, len(lens), int(lens.sum()), n_logits)
+
+
+def test_a_windowed_architecture_counts_each_slots_context():
+    from chipbench import archs
+    conf = json.loads((HERE / "data" / "tiny_window.json").read_text())
+    win = archs.load(conf["architecture"], HERE / "data").Shapes.of(
+        conf["model"], conf["dtype"])
+    dense = dense_gqa.Shapes.of(conf["model"], conf["dtype"])
+    assert win.window == 6
+    # a slot reads at most the window, whatever the others read
+    assert win.step_flops(step([3, 10, 40], 2)) == \
+        dense.step_flops(step([3, 6, 6], 2)) < dense.step_flops(
+            step([3, 10, 40], 2))
+    assert win.step_bytes(step([3, 10, 40], 2)) == \
+        dense.step_bytes(step([3, 6, 6], 2))
 
 
 def test_least_seconds_names_its_bound():
@@ -277,10 +327,33 @@ def test_command_without_tpu_exits_nonzero_with_no_result():
     assert "no TPU" in p.stderr
 
 
+def test_unknown_architecture_exits_before_looking_for_a_chip(tmp_path):
+    shutil.copytree(BENCH, tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for c in bench["configs"]:
+        conf = json.loads((ROOT / c["file"]).read_text())
+        conf["architecture"] = "no_such_architecture"
+        (tmp_path / c["file"]).write_text(json.dumps(conf))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "benchmarks/chip/run.py", "--workload",
+         "minitron_4b.chat", "--seed", str(2**31 + 5), "--seconds", "1"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0 and p.stdout.strip() == ""
+    assert "unknown architecture 'no_such_architecture'; known: " \
+        "['dense_gqa']" in p.stderr
+    assert "no TPU" not in p.stderr
+
+
 def test_benchmark_names_files_that_exist():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     for c in bench["configs"]:
         assert (ROOT / c["file"]).exists()
+        conf = json.loads((ROOT / c["file"]).read_text())
+        assert (BENCH / "chipbench" / "archs"
+                / f"{conf['architecture']}.py").exists()
     for w in bench["workloads"]:
         assert (BENCH / "traffic" / f"{w['traffic']}.json").exists()
     for m in bench["per_layer"]:
