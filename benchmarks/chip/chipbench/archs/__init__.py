@@ -18,7 +18,8 @@ gives:
   and ``step_bytes(step)`` the work one ``records.StepRecord`` needs.
 
 ``model`` is the configuration's ``"model"`` block.  A new architecture
-is a new file here and a configuration that names it.
+is a new file here and a configuration that names it.  A test derives
+the list of architectures from this directory and never spells it out.
 """
 from __future__ import annotations
 

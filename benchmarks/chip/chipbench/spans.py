@@ -20,6 +20,13 @@ decode program's compiled HLO text (``op_names``: instruction name ->
 ``op_name``, ``hlo_op_names``).  ``reduce`` works on a ``SpanTrace``
 alone, so it is tested on a synthetic one with known intervals.  All
 times are nanoseconds on the trace's one clock.
+
+A per-layer reader gets ``reduce``'s dict as ``ctx.spans``.  Besides
+the step's own timings, it holds every count the program puts on its
+``serve.step`` spans (``step_stats``, a mean per step) and the time of
+every other ``serve.*`` span of the program (``span_ms``), so that a
+count or a host span that a new model adds to the serving loop is read
+by a new reader file alone.
 """
 from __future__ import annotations
 
@@ -33,6 +40,11 @@ from .xplane import _labels, _union, self_times
 SCOPES = ("layers", "attn", "kv_write", "ffn", "unembed")
 STEP = "serve.step"
 CHILDREN = ("serve.admit", "serve.launch", "serve.pull", "serve.pick")
+# ``serve.step`` stats that are no counts: the step's number, the
+# profiler's own (``_r``), and the ids of the requests a step admitted
+# or finished, a list that reaches the trace as a string of ids or, one
+# id alone, as a number
+NOT_COUNTS = ("step_num", "_", "rids_")
 
 
 @dataclass
@@ -143,7 +155,12 @@ def reduce(st: SpanTrace, program: str = "decode_step",
     steps: ``launch_ms`` (start of the step to the end of its launch),
     ``pull_ms`` (end of the step's decode program on the latest chip to
     the end of the pull; the pull's earlier part waits on the device),
-    ``pick_ms``; ``prefill_share`` (%) from the steps' stats; and, where
+    ``pick_ms``; ``prefill_share`` (%) from the steps' stats;
+    ``step_stats``, each count on the steps' stats (every number but
+    ``NOT_COUNTS``; a stat that is no number on some step is no count),
+    its mean per step, a step without it counting 0; ``span_ms``, each
+    ``serve.*`` span other than the step and its four children by name,
+    its time inside the steps, ms a step; and, where
     the trace holds device ops, the decode program's device self time by
     innermost scope of ``scopes``, averaged over the chips too
     (``scope_ms``, ``""`` for the ops in none; an op with no scope of
@@ -177,6 +194,15 @@ def reduce(st: SpanTrace, program: str = "decode_step",
     pre = sum(x["stats"].get("prefill", 0) for x in steps)
     dec = sum(x["stats"].get("decode", 0) for x in steps)
     out["prefill_share"] = 100.0 * pre / (pre + dec) if pre + dec else None
+    out["step_stats"] = _counts([x["stats"] for x in steps])
+    step_iv = [(x["start"], x["end"]) for x in steps]
+    step_starts = [a for a, _ in step_iv]
+    inside: dict[str, float] = defaultdict(float)
+    for name, s, d, _ in st.host:
+        if name.startswith("serve.") and name not in (STEP, *CHILDREN):
+            for a, b in _overlapping(step_iv, step_starts, s, s + d):
+                inside[name] += min(b, s + d) - max(a, s)
+    out["span_ms"] = {name: t / n * 1e-6 for name, t in sorted(inside.items())}
 
     devices = sorted(d for d, ops in st.ops.items() if ops)
     if not devices:
@@ -185,8 +211,6 @@ def reduce(st: SpanTrace, program: str = "decode_step",
     prog = 0.0
     idle = idle_in_steps = 0.0
     pieces: list[tuple[float, float]] = []
-    step_iv = [(x["start"], x["end"]) for x in steps]
-    step_starts = [a for a, _ in step_iv]
     edges_host = sorted({t for _, s, d, _ in st.host for t in (s, s + d)})
     for dev in devices:
         runs = sorted((s, s + d) for name, s, d in st.modules.get(dev, [])
@@ -229,6 +253,20 @@ def reduce(st: SpanTrace, program: str = "decode_step",
     out["idle_by_span_ms"] = {name: t * per for name, t in sorted(
         idle_by_span.items(), key=lambda kv: -kv[1])}
     return out
+
+
+def _counts(stats: list[dict]) -> dict[str, float]:
+    """Each count of the steps' ``stats``, its mean over the steps."""
+    sums: dict[str, float] = defaultdict(float)
+    others = set()
+    for one in stats:
+        for key, value in one.items():
+            if isinstance(value, (int, float)):
+                sums[key] += value
+            else:
+                others.add(key)
+    return {key: t / len(stats) for key, t in sorted(sums.items())
+            if key not in others and not key.startswith(NOT_COUNTS)}
 
 
 def _inherit(ops, scopes):

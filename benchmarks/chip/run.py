@@ -30,8 +30,8 @@ One run, in one process:
    in which the program's own tracer puts its spans.
 
 Without a TPU, or with fewer chips than the cell asks for, it prints no
-result and exits 2; with an unknown architecture it exits before it
-looks for one.
+result and exits 2; with an unknown architecture, or a reader that does
+not load, it exits before it looks for one.
 """
 from __future__ import annotations
 
@@ -83,13 +83,16 @@ def load_cell(name: str, root: Path = ROOT) -> SimpleNamespace:
     def applies(metric):
         return name in metric.get("workloads", [name])
 
+    per_layer = [m for m in bench["per_layer"] if applies(m)]
+    for m in per_layer:     # a reader that does not load stops the run here
+        reader(m["name"])
     return SimpleNamespace(
         name=name, chips=cell["chips"], config=config,
         arch=archs.load(config["architecture"]),
         mix=traffic.load(HERE / "traffic" / f"{cell['traffic']}.json"),
         limits=check.load_limits(HERE / "limits" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
-        per_layer=[m for m in bench["per_layer"] if applies(m)])
+        per_layer=per_layer)
 
 
 def chips_or_exit(n: int):

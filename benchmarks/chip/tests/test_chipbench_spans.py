@@ -93,6 +93,35 @@ def test_span_readings_of_known_intervals(devices):
         "bench.track": 80 / 2 * ms, "host idle (no span)": 5 / 2 * ms})
 
 
+def test_step_counts_and_the_programs_other_spans():
+    """``step_stats``: each count on the steps' stats, a mean per step;
+    ``span_ms``: each other ``serve.*`` span of the program, its time
+    inside the steps, a step's.  Neither moves another reading."""
+    plain = spans.reduce(synthetic())
+    assert plain["step_stats"] == {"decode": 0.5, "prefill": 1.5}
+    assert plain["span_ms"] == {}
+    st = synthetic()
+    more = ({"queued": 3, "load": 0.25, "compiled": 2, "experts": [1, 2],
+             "rids_admitted": "7 8", "_r": 1},
+            {"queued": 0, "load": 0.75, "experts": [3], "rids_admitted": 9,
+             "_r": 1})
+    for (_, _, _, stats), extra in zip(
+            [e for e in st.host if e[0] == "serve.step"], more):
+        stats.update(extra)
+    st.host += [("serve.reset", 90, 50, {}),    # 40 inside the first step
+                ("serve.reset", 600, 10, {}),   # inside the second
+                ("serve.reset", 820, 30, {}),   # after the steps
+                ("serve.route", 530, 4, {"tokens": 12})]
+    r = spans.reduce(st)
+    assert r["step_stats"] == {"compiled": 1.0, "decode": 0.5, "load": 0.5,
+                               "prefill": 1.5, "queued": 1.5}
+    assert r["span_ms"] == {"serve.reset": (40 + 10) / 2 * 1e-6,
+                            "serve.route": 4 / 2 * 1e-6}
+    new = ("step_stats", "span_ms")
+    assert {k: v for k, v in r.items() if k not in new} == \
+        {k: v for k, v in plain.items() if k not in new}
+
+
 def test_a_pull_after_the_device_is_done_counts_whole():
     st = synthetic()
     # the first step's program ends before its pull starts
@@ -227,6 +256,15 @@ def test_traced_run_with_the_programs_spans_on_the_cpu(tmp_path, capsys):
     r = json.loads(line[-1].removeprefix("program spans: "))
     assert r["steps"] > 0 and r["launch_ms"] > 0 and r["pick_ms"] > 0
     assert 0 < r["prefill_share"] < 100
+    # the step's counts, whatever form the profiler gives them; the
+    # request ids, a number where a step admitted one, are no count
+    counts = r["step_stats"]
+    assert {"admitted", "prefill", "decode", "busy", "queued"} <= set(counts)
+    assert not any(k.startswith(spans.NOT_COUNTS) for k in counts)
+    assert counts["busy"] == pytest.approx(counts["prefill"] + counts["decode"])
+    assert r["prefill_share"] == pytest.approx(
+        100 * counts["prefill"] / counts["busy"])
+    assert r["span_ms"] == {}
     # the CPU's trace holds no device plane, so only the host spans read
     got = result["metrics"]
     assert got["launch_ms.chat"]["value"] == r["launch_ms"]

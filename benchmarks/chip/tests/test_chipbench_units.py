@@ -27,6 +27,15 @@ from chipbench.peaks import peak_for  # noqa: E402
 from chipbench.records import RequestRecord, StepRecord  # noqa: E402
 
 
+BENCHMARK = json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+def architecture(config: dict) -> str:
+    """The architecture that a configuration entry of ``BENCHMARK.json``
+    names in its file."""
+    return json.loads((ROOT / config["file"]).read_text())["architecture"]
+
+
 def shapes(name: str) -> dense_gqa.Shapes:
     conf = json.loads((BENCH / "configs" / f"{name}.json").read_text())
     return dense_gqa.Shapes.of(conf["model"], conf["dtype"])
@@ -80,7 +89,8 @@ def dense_counts_of_totals(s, n_tokens: int, sum_ctx: int, n_logits: int):
     return flops, nbytes
 
 
-@pytest.mark.parametrize("name", ["minitron_4b", "granite_8b"])
+@pytest.mark.parametrize("name", [c["name"] for c in BENCHMARK["configs"]
+                                  if architecture(c) == "dense_gqa"])
 def test_dense_counts_of_a_step_record_are_those_of_its_totals(name):
     s = shapes(name)
     rng = np.random.default_rng(2**31 + 3)
@@ -341,10 +351,33 @@ def test_unknown_architecture_exits_before_looking_for_a_chip(tmp_path):
         [sys.executable, "benchmarks/chip/run.py", "--workload",
          "minitron_4b.chat", "--seed", str(2**31 + 5), "--seconds", "1"],
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
+    known = sorted(
+        f.stem for f in (tmp_path / "benchmarks" / "chip" / "chipbench"
+                         / "archs").glob("*.py") if not f.name.startswith("_"))
+    assert "dense_gqa" in known
     assert p.returncode != 0 and p.stdout.strip() == ""
     assert "unknown architecture 'no_such_architecture'; known: " \
-        "['dense_gqa']" in p.stderr
+        f"{known}" in p.stderr
     assert "no TPU" not in p.stderr
+
+
+def test_a_reader_that_does_not_load_stops_the_cell_before_the_chip(
+        tmp_path):
+    """``run.load_cell``, which runs before the look for a chip, loads
+    every reader of the cell."""
+    import run
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = bench["workloads"][0]["name"]
+    assert run.load_cell(cell).per_layer
+    bench["per_layer"].append(dict(bench["per_layer"][0],
+                                   name="no_such_reader.chat",
+                                   workloads=[cell]))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    for c in bench["configs"]:
+        (tmp_path / c["file"]).parent.mkdir(parents=True, exist_ok=True)
+        shutil.copy(ROOT / c["file"], tmp_path / c["file"])
+    with pytest.raises(FileNotFoundError, match="no_such_reader.chat"):
+        run.load_cell(cell, tmp_path)
 
 
 def test_benchmark_names_files_that_exist():
